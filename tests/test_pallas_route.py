@@ -377,17 +377,22 @@ def test_self_send_one_ms_floor(kernel):
 # ------------------------------------------------------------ cost model
 
 
-def test_route_vmem_model_fits_shipped_configs():
-    """The named cost model at the launch shapes the drivers use: the
-    headline ring must fit the scoped-VMEM budget at some block size,
-    and a deliberately monstrous ring must be REJECTED when enforcing
-    (the r5 no-unbudgeted-launch gate) yet still pick a block in
-    interpret mode (CPU tests never see Mosaic's VMEM)."""
+def test_route_block_obeys_tiling_or_refuses():
+    """A real launch's destination block obeys the TPU (8, 128) tiling
+    rule (the [1, H, blk] count block: blk a multiple of 128 or every
+    destination) inside the VMEM model, or the launcher refuses at
+    build time naming the rule and the XLA path — the headline ring
+    (H256 x C12, F=2: ~1 MB per destination) is refused.  Interpret
+    mode still picks a block (CPU tests never see Mosaic)."""
     from wittgenstein_tpu.ops.pallas_route import _pick_route_block
-    blk = _pick_route_block(2048, 4096, 256, 12, 2, 256)
-    assert blk >= 1
-    assert route_row_bytes(256, 12, 2) * blk + \
-        route_fixed_bytes(4096, 2) <= 6 << 20
+    with pytest.raises(ValueError, match=r"\(8, 128\) tiling.*XLA"):
+        _pick_route_block(2048, 4096, 256, 12, 2, 256)
+    for ns in (128, 256, 64):
+        blk = _pick_route_block(ns, 256, 8, 4, 1, 256)
+        assert blk % 128 == 0 or blk == ns
+        assert ns % blk == 0
+        assert route_row_bytes(8, 4, 1) * blk + \
+            route_fixed_bytes(256, 1) <= 6 << 20
     huge = dict(ns=64, m=256, horizon=1 << 15, cap=512, f=8, chunk=256)
     with pytest.raises(ValueError, match="VMEM"):
         _pick_route_block(**huge, enforce=True)

@@ -298,12 +298,23 @@ def test_fleet_workers_share_memo_table_in_process(tmp_path):
                      memo_table=table_dir)
     wb = FleetWorker(fd, "wb", lease_ttl_s=30.0,
                      memo_table=table_dir)
+    # the driver reads each probe's result from the ledger and, after
+    # the last one, aggregates the workers' stats files at once:
+    # publish them before each ledger row lands
+    for w in (wa, wb):
+        def append_ledger(*a, _w=w, _append=w.sched._append_ledger, **k):
+            _w.write_stats()
+            return _append(*a, **k)
+        w.sched._append_ledger = append_ledger
     box = {}
 
+    # poll_s: the driver gives a settled prefix two polls to land in
+    # the table before it runs the probes unforked; wa puts it at the
+    # end of its step, which under a loaded host outlasts 2 x 0.05 s
     def drive():
         box["run"] = _run_search_fleet(
             spec, splan, fleet_dir=fd, workers=2, spawn=False,
-            poll_s=0.05, timeout_s=300.0)
+            poll_s=0.5, timeout_s=300.0)
 
     t = threading.Thread(target=drive, name="search-driver")
     t.start()

@@ -123,8 +123,6 @@ def bench_pingpong():
     at the witness by 800 ms (README.md:123-135: 1000 at 700 ms)."""
     from wittgenstein_tpu.models.pingpong import PingPong
     proto = PingPong(node_count=1000)
-    # 4 seeds: the [seeds, H*N*C] mailbox planes stay at 524 MB, under
-    # the TPU runtime's ~1 GB single-buffer limit (BENCH_NOTES.md r3).
     seeds = 4
 
     def check(nets, ps):
@@ -161,9 +159,10 @@ def bench_sanfermin():
     block's stragglers at the sibling block's first ids.  The rotated
     pick order (models/sanfermin._pick_offset) makes every pick index a
     requester<->candidate bijection — measured ZERO drops at 4096 nodes
-    with inbox 12 (r5) — so 16 now carries margin, and box_split=2
-    keeps each mailbox sub-plane at 537 MB, under the TPU runtime's
-    ~1 GB single-buffer execution limit (BENCH_NOTES.md r3)."""
+    with inbox 12 (r5) — so 16 now carries margin.  box_split=2 (537 MB
+    sub-planes) was sized for a ~1 GB buffer limit of an earlier remote
+    runtime that a v5e does not have (a 1.5 GB buffer works, PR 21 chip
+    run); the layout is bit-identical either way."""
     import dataclasses
 
     from wittgenstein_tpu.models.sanfermin import SanFermin
@@ -787,7 +786,11 @@ def bench_fleet_smoke():
     running, `deduped >= 1`) and lease-partitioned completion of the
     rest (every request `done`, aggregate throughput reported).  The
     workers' published stats snapshots are the measurement source —
-    the same files `run_grid(workers=N)` aggregates."""
+    the same files `run_grid(workers=N)` aggregates.  The workers run
+    on the CPU (``JAX_PLATFORMS=cpu``): the stage tests lease and queue
+    semantics, not the device, and on a TPU host the chip belongs to
+    this process."""
+    import os
     import tempfile
     import time
 
@@ -796,7 +799,7 @@ def bench_fleet_smoke():
     from wittgenstein_tpu.serve import FleetService
     from wittgenstein_tpu.serve.fleet import (FleetWorker,
                                               aggregate_worker_stats,
-                                              spawn_worker)
+                                              spawn_workers)
     from wittgenstein_tpu.serve.spec import ScenarioSpec
 
     mk = lambda seed: ScenarioSpec(          # noqa: E731
@@ -819,8 +822,8 @@ def bench_fleet_smoke():
         rids = [svc.submit(mk(s).to_json())["id"] for s in
                 (0, 1, 2, 3)]
         t0 = time.perf_counter()
-        procs = [spawn_worker(tmp, f"w{i}", idle_exit_s=2.0,
-                              max_wall_s=300.0) for i in (0, 1)]
+        procs = spawn_workers(tmp, 2, idle_exit_s=2.0, max_wall_s=300.0,
+                              env=dict(os.environ, JAX_PLATFORMS="cpu"))
         deadline = time.time() + 300.0
         for p in procs:
             while p.poll() is None and time.time() < deadline:
@@ -1309,6 +1312,7 @@ def main(argv=None) -> int:
     if write_history:
         from wittgenstein_tpu.obs.regress import BenchHistory
         hist = BenchHistory(args.history)
+    failed = []
     for name in names:
         metric = METRIC_NAMES.get(name, f"{name}_agg_sim_ms_per_sec")
         try:
@@ -1316,12 +1320,19 @@ def main(argv=None) -> int:
             if "metric" not in res:
                 res = {"metric": metric, **res}
         except Exception as e:                  # noqa: BLE001 — per-config
+            # the other stages still run; the suite's exit code says
+            # that this one failed
             res = {"metric": metric,
                    "error": f"{type(e).__name__}: {e!s:.300}"}
+        if "error" in res:
+            failed.append(name)
         _append_ledger(name, res)
         if hist is not None:
             _append_history(hist, name, res, round_id)
         print(json.dumps(res), flush=True)
+    if failed:
+        print(f"bench_suite: {len(failed)} stage(s) failed: "
+              f"{', '.join(failed)}", file=sys.stderr)
     if args.check_regressions:
         if hist is None:
             print("bench_suite: --check-regressions needs history "
@@ -1334,8 +1345,8 @@ def main(argv=None) -> int:
                           **summary}), flush=True)
         if findings:
             print(regress.format_findings(findings), file=sys.stderr)
-        return code
-    return 0
+        return code or int(bool(failed))
+    return int(bool(failed))
 
 
 if __name__ == "__main__":

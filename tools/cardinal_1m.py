@@ -4,8 +4,8 @@ Builds HandelCardinal at node_count=2^20 and either (a) GSPMD-shards the
 node axis over an n-device virtual CPU mesh (the same layout
 dryrun_multichip validates), or (b) with WTPU_CARDINAL_PLATFORM=tpu runs
 single-device on the REAL chip (state 11.7 GB vs 16 GB HBM; the mailbox
-ring is split into node-range sub-planes, EngineConfig.box_split, to
-stay under the runtime's ~1 GB single-buffer execution limit).  Runs
+ring is split into node-range sub-planes, EngineConfig.box_split, of at
+most 800 MB each).  Runs
 >= 100 simulated ms and asserts zero drops/clamps/evictions.  Writes
 reports/CARDINAL_<label>.md — every config-dependent value in the
 report prose is derived from the live config (the r3 template hardcoded
@@ -82,9 +82,10 @@ def main():
         pairing_time=4, dissemination_period_ms=20, fast_path=10,
         queue_cap=queue_cap, inbox_cap=inbox_cap, horizon=96,
         network_latency_name="NetworkUniformLatency(90)")
-    # Keep every ring sub-plane under the TPU runtime's ~1 GB
-    # single-buffer execution limit (BENCH_NOTES.md r3): at 2^20 x hz128
-    # x ic4 a monolithic plane is 2.1 GB -> split 4 ways (537 MB each).
+    # Ring sub-planes of at most 800 MB (at 2^20 x hz128 x ic4 a
+    # monolithic plane is 2.1 GB -> split 4 ways, 537 MB each).  Sized
+    # for a ~1 GB buffer limit of an earlier remote runtime; a v5e runs a
+    # 1.5 GB buffer (PR 21 chip run), and the layout is bit-identical.
     plane_bytes = 4 * proto.cfg.horizon * n * proto.cfg.inbox_cap
     min_split = max(1, -(-plane_bytes // (800 * 1024 * 1024)))
     # Round up to a power of two: box_split must divide the (power-of-two)
@@ -140,14 +141,10 @@ def main():
         # superstep=2: fused 2-ms engine pass — halves per-ms fixed
         # cost on the virtual-mesh runs.
         base_step = scan_chunk(proto, chunk, t0_mod=0, superstep=2)
-    # Selective >=1MB-leaf donation (network.split_donate_jit — the
-    # Runner donate="big" mechanics, validated on this hardware in r3):
-    # without it the while-loop carry cannot alias the 11.7 GB input
-    # state and the program OOMs at compile (17.9 GB HLO temp vs
-    # 15.75 GB HBM, observed 2026-07-31).
-    from wittgenstein_tpu.core.network import (split_donate_jit,
-                                                split_spec)
-    step = split_donate_jit(base_step, *split_spec((net, ps)))
+    # Whole-state donation: without it the carry cannot alias the
+    # 11.7 GB input state and the program needs 17.9 GB of HLO temp
+    # against 15.75 GB of HBM.
+    step = jax.jit(base_step, donate_argnums=(0, 1))
     t0 = time.perf_counter()
     with mesh:
         net, ps = step(net, ps)
@@ -196,8 +193,8 @@ def main():
         plat = jax.devices()[0].device_kind
         topo = (f"single REAL {plat} chip ({jax.default_backend()} "
                 f"backend), mailbox ring split into {cfg.box_split} "
-                "node-range sub-planes (EngineConfig.box_split) to stay "
-                "under the runtime's ~1 GB single-buffer execution limit")
+                "node-range sub-planes (EngineConfig.box_split) of at "
+                "most 800 MB each")
         per_chip = (f"measured here directly: {state_bytes / 1e9:.2f} GB "
                     "resident on one chip's 16 GB HBM.")
     elif N_DEV > 1:

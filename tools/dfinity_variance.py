@@ -16,13 +16,6 @@ import time
 REPO = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-from wittgenstein_tpu.utils.platform import (force_virtual_cpu,  # noqa: E402
-                                             probe_backend)
-
-if not probe_backend(timeout_s=120):
-    print("backend down -> CPU", flush=True)
-    force_virtual_cpu(1)
-
 import jax                                             # noqa: E402
 import numpy as np                                     # noqa: E402
 

@@ -243,7 +243,7 @@ def fleet_load_once(workers: int, per: int, *, base_dir,
 
     from wittgenstein_tpu.serve import FleetService
     from wittgenstein_tpu.serve.fleet import (aggregate_worker_stats,
-                                              fleet_paths, spawn_worker)
+                                              fleet_paths, spawn_workers)
 
     d = os.path.join(base_dir, f"fleet-{workers}w")
     svc = FleetService(d, tenants=fleet_tenants())
@@ -254,10 +254,8 @@ def fleet_load_once(workers: int, per: int, *, base_dir,
         # would interleave unrelated runs on one timeline
         tdir = os.path.join(timeline, f"{workers}w")
         os.makedirs(tdir, exist_ok=True)
-    procs = [spawn_worker(d, f"w{i}", lease_ttl_s=lease_ttl_s,
-                          idle_exit_s=4.0, max_wall_s=900.0,
-                          timeline=tdir)
-             for i in range(workers)]
+    procs = spawn_workers(d, workers, lease_ttl_s=lease_ttl_s,
+                          idle_exit_s=4.0, max_wall_s=900.0, timeline=tdir)
     stats_glob = os.path.join(fleet_paths(d)["stats_dir"],
                               "worker-*.json")
     t_ready = time.time()

@@ -37,17 +37,18 @@ def account(proto, label):
     total = sum(b for _, b in rows)
     big = sum(b for _, b in rows if b >= 1 << 20)
     # Peak without donation: input + output state live together (2x);
-    # with donate="big" the >=1MB leaves are reused in place, so peak ~
-    # total + big-transient margin (XLA temporaries are dominated by the
-    # largest sort/scatter operands, ~1 extra copy of the ring slice).
+    # with the whole state donated (Runner's default) every leaf is
+    # reused in place, so peak ~ total + a transient margin (XLA
+    # temporaries are dominated by the largest sort/scatter operands,
+    # ~1 extra copy of the ring slice).
     peak_nodonate = 2 * total
-    peak_big = total + (total - big) + 0.25 * big
+    peak_donated = total + 0.25 * big
     print(f"\n== {label}: total {total / 1e9:.2f} GB "
           f"(big leaves {big / 1e9:.2f} GB)")
     for name, b in rows[:8]:
         print(f"   {b / 1e9:7.3f} GB  {name}")
     return {"label": label, "rows": rows, "total": total, "big": big,
-            "peak_nodonate": peak_nodonate, "peak_big": peak_big}
+            "peak_nodonate": peak_nodonate, "peak_donated": peak_donated}
 
 
 def main():
@@ -77,20 +78,19 @@ def main():
         "emission, pool-free, horizon 256, inbox 8; queue_cap at the",
         "int32-index ceiling for exact mode, 8 for cardinal).  Peaks:",
         "undonated step = 2x state (input + output buffers both live);",
-        "`Runner(donate=\"big\")` reuses every >= 1 MB leaf in place",
-        "(tests/test_engine.py proves bit-identity), leaving ~2x only the",
-        "small leaves plus a ~25% transient margin on the big ones.",
+        "with the whole state donated (Runner's default) every leaf is",
+        "reused in place, leaving a ~25% transient margin on the big ones.",
         "",
-        "| config | state GB | peak (no donation) | peak (donate=big) |"
+        "| config | state GB | peak (no donation) | peak (donated) |"
         " v5e chips (16 GB) |",
         "|---|---|---|---|---|",
     ]
     for c in cfgs:
-        chips = max(1, int(-(-c["peak_big"] // HBM_PER_CHIP)))
+        chips = max(1, int(-(-c["peak_donated"] // HBM_PER_CHIP)))
         lines.append(
             f"| {c['label']} | {c['total'] / 1e9:.2f} "
             f"| {c['peak_nodonate'] / 1e9:.2f} "
-            f"| {c['peak_big'] / 1e9:.2f} | {chips} |")
+            f"| {c['peak_donated'] / 1e9:.2f} | {chips} |")
     lines += [
         "",
         "Top leaves (exact-hashed 65536):",

@@ -25,13 +25,14 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops import prng
 
 TAG_P2P = 0x50325030  # domain separation for peer-graph draws
 TAG_SHUF = 0x50325346  # flood fan-out shuffle draws
 
-_BIG = jnp.int32(0x7FFFFFFF)
+_BIG = np.int32(0x7FFFFFFF)   # numpy: a jnp constant would init the backend at import
 
 
 def _scatter_adjacency(src, dst, keep, n, max_degree):

@@ -57,11 +57,9 @@ class EngineConfig:
     msg_discard_time: int = 1 << 30
     spill_cap: int = 0          # S: far-future parked messages (0 = clamp)
     # P: ring planes are split into P node-range sub-planes of N/P nodes
-    # each.  The TPU runtime faults on executions touching any single
-    # buffer past ~1 GB (BENCH_NOTES.md r3), which capped cardinal mode
-    # at 65k nodes/chip and exact-mode seed batches at 16; splitting by
-    # node range keeps every sub-plane under the limit while the flat
-    # per-sub-plane layout stays identical (bit-equal for any P —
+    # each, so each sub-plane's flat int32 index H*Ns*C stays below 2^31
+    # at 1M-class node counts, while the flat per-sub-plane layout stays
+    # identical (bit-equal for any P —
     # tests/test_engine.py::test_box_split_bit_equal).
     box_split: int = 1
 
@@ -136,12 +134,11 @@ class NetState:
     # scan-carry layout and the scatter/slice layouts agree — multi-dim ring
     # buffers made XLA:TPU relayout the whole ring every iteration (hundreds
     # of MB/step).  The F payload words live in F separate PLANES (not one
-    # [F*H*N*C] buffer): the TPU runtime faults on executions touching
-    # single buffers past ~1 GB (observed 2026-07-31 at 2048 nodes x 8
-    # vmapped seeds), and per-plane scatters need no cross-field OOB
-    # sentinel arithmetic.  Each plane is further split into
-    # P = cfg.box_split node-range SUB-planes of Ns = N/P nodes (same
-    # buffer-size limit, at 100k-1M node counts): cell (h, n, c) with
+    # [F*H*N*C] buffer): per-plane scatters need no cross-field OOB
+    # sentinel arithmetic, and each plane's flat index range is F times
+    # smaller.  Each plane is further split into
+    # P = cfg.box_split node-range SUB-planes of Ns = N/P nodes (int32
+    # index range at 100k-1M node counts): cell (h, n, c) with
     # n in sub-range j lives at flat index (h*Ns + n - j*Ns)*C + c of
     # sub-plane j.  P == 1 reproduces the round-3 layout exactly.
     box_data: tuple             # F*P x int32 [H*Ns*C] (plane f*P + j)

@@ -715,19 +715,17 @@ def run_grid_fleet(grid: SweepGrid, plan_: MatrixPlan | None = None, *,
     (a directory) turns each worker's host-plane flight recorder ON —
     one ``spans-<worker>.jsonl`` per worker under it, a dead worker's
     torn tail included (tools/timeline.py renders them)."""
-    from ..serve.fleet import aggregate_worker_stats, spawn_worker
+    from ..serve.fleet import aggregate_worker_stats, spawn_workers
 
     plan_ = plan_ or plan(grid)
     t0 = time.time()
     requests = fleet_enqueue(plan_, fleet_dir)
     procs = []
     if spawn:
-        procs = [spawn_worker(fleet_dir, f"w{i}",
+        procs = spawn_workers(fleet_dir, workers,
                               lease_ttl_s=lease_ttl_s,
                               idle_exit_s=idle_exit_s,
-                              max_wall_s=timeout_s,
-                              timeline=timeline)
-                 for i in range(int(workers))]
+                              max_wall_s=timeout_s, timeline=timeline)
     if on_spawned is not None:
         on_spawned(procs)
     try:

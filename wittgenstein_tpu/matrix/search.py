@@ -1142,7 +1142,7 @@ def _run_search_fleet(spec: SearchSpec, splan: SearchPlan, *,
     import uuid
 
     from ..serve.fleet import (aggregate_worker_stats, fleet_paths,
-                               spawn_worker)
+                               spawn_workers)
     from ..serve.journal import SubmissionJournal
 
     mplan = splan.mplan
@@ -1172,11 +1172,10 @@ def _run_search_fleet(spec: SearchSpec, splan: SearchPlan, *,
     seq = itertools.count()
     procs = []
     if spawn:
-        procs = [spawn_worker(fleet_dir, f"w{i}",
+        procs = spawn_workers(fleet_dir, workers,
                               lease_ttl_s=lease_ttl_s,
                               idle_exit_s=None, max_wall_s=timeout_s,
                               memo_table=table_dir, timeline=timeline)
-                 for i in range(int(workers))]
     if on_spawned is not None:
         on_spawned(procs)
     states = [_SliceState(sl, splan.coarse_idx)

@@ -29,9 +29,6 @@ TPU-native design:
   reference's startNewMining(privateMinerBlock); "they won" switches the
   mining base back to the public head.
 
-Operational note: keep Runner chunks <= ~10_000 ticks on TPU — this model's
-step body is control-flow heavy (strategy while_loops) and very long
-single scans have crashed the current TPU runtime; chunking costs nothing.
 Blockchain sims run at 5-10k nodes max in the reference (CasperIMD.java:714)
 and N~10 miners here, so the TPU win comes from vmapping seeds/sweeps, not
 from node-axis width.

@@ -58,7 +58,7 @@ from ._levels import (LevelMixin, get_bit_rows as _get_bit_rows,
 TAG_BAD = 0x47424144      # bad-node choice
 TAG_PERM = 0x47504552     # per-(node, level) peer-order permutation
 U32 = jnp.uint32
-BIG = jnp.int32(1 << 30)
+BIG = np.int32(1 << 30)
 
 
 @struct.dataclass

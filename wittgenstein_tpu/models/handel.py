@@ -74,7 +74,7 @@ TAG_LEVEL = 0x484C564C    # random level pick in checkSigs
 TAG_EMIT = 0x48454D49     # hashed emission-order permutation keys
 
 U32 = jnp.uint32
-BIG = jnp.int32(1 << 30)
+BIG = np.int32(1 << 30)
 
 
 _sibling_base = sibling_base  # shared geometry (_levels.sibling_base)
@@ -97,13 +97,10 @@ class HandelState:
     # The queued sig rows, stored as `state_split` node-range PIECES of
     # [N/P, Q, W] each (P == 1 -> a 1-tuple; layouts identical,
     # bit-equal for any P).  Same motivation as EngineConfig.box_split:
-    # the TPU runtime faults on executions touching any single buffer
-    # past ~1 GB, and [32768, Q, 1024] u32 pads to 1.07 GB under (8,
-    # 128) tiling for ANY Q <= 8 (BENCH_NOTES.md r4) — splitting by
-    # node range is what lets exact mode reach 32k on one chip.  The
-    # receive merge and verification scoring also compute their
-    # [*, Q|S, W] transients per piece, bounding peak memory the same
-    # way.
+    # each piece's flat gathers index Ns*Q*W int32 cells, which must
+    # stay below 2^31 (the constructor checks it), and the receive merge
+    # and verification scoring compute their [*, Q|S, W] transients per
+    # piece, bounding peak memory at the 32k exact tier.
     q_sig: tuple               # P x u32 [N/P, Q, W] — entry's level bits
     pool: jnp.ndarray          # u32 [N, R, W] — outgoing snapshots per round
     emission: jnp.ndarray      # int32 [N, N] — per-level sorted receiver ids

@@ -69,7 +69,7 @@ from ._levels import (LevelMixin, StaticScheduleMixin,
 from .handel import TAG_BAD, TAG_EMIT, TAG_LEVEL, TAG_RANK, TAG_START
 
 U32 = jnp.uint32
-BIG = jnp.int32(1 << 30)
+BIG = np.int32(1 << 30)
 
 
 @struct.dataclass

@@ -111,15 +111,12 @@ def _memory_block(compiled) -> dict:
 
 
 def _cost_block(compiled) -> dict:
-    """`Compiled.cost_analysis()` flops/bytes (jax 0.4.x returns a
-    per-device LIST of dicts; newer versions a dict — both shapes
-    accepted, missing analysis degrades to {})."""
+    """`Compiled.cost_analysis()` flops/bytes (a missing analysis
+    degrades to {})."""
     try:
         ca = compiled.cost_analysis()
     except Exception:                               # noqa: BLE001
         return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     if not isinstance(ca, dict):
         return {}
     out = {}

@@ -252,16 +252,21 @@ def _make_kernel(f: int, cap: int, chunk: int, n_waves: int):
 
 def _pick_route_block(ns: int, m: int, horizon: int, cap: int,
                       f: int, chunk: int, enforce: bool = True) -> int:
-    """Destination-block size: `_pick_block` over the per-row model,
-    then shrink further until the fixed (message-vector + wave) VMEM
-    also fits — _pick_block only scales the per-row term.
+    """Destination-block size within the VMEM cost model.
+
+    A real (Mosaic) launch must also obey the TPU's (8, 128) tiling
+    rule: the count block is [1, H, blk], so its last dimension `blk`
+    must be a multiple of 128 or all `ns` destinations (the slab
+    block's second-minor `blk` then satisfies the 8-rule too).  The
+    largest such block that fits the budget wins; when none fits, the
+    launcher refuses at build time, naming the rule and the XLA path.
+    At the headline ring (H256 x C12, F=2) one destination costs about
+    1 MB, so no legal block fits and the kernel is refused there.
 
     ``enforce=False`` (interpret mode — CPU tests at arbitrary ring
-    shapes) still SHRINKS by the model but never raises: the
-    interpreter has no scoped VMEM to overflow, and bit-identity
-    coverage must not depend on a chip-sized config.  Real launches
-    keep the raising gate — the r5 lesson that an unbudgeted Mosaic
-    compile is an error, not a perf tradeoff."""
+    shapes) only SHRINKS by the model and never raises: the
+    interpreter has neither tiling nor scoped VMEM, and bit-identity
+    coverage must not depend on a chip-sized config."""
     row = route_row_bytes(horizon, cap, f, chunk)
     fixed = route_fixed_bytes(m, f, chunk)
     if not enforce:
@@ -269,18 +274,21 @@ def _pick_route_block(ns: int, m: int, horizon: int, cap: int,
         while blk > 1 and (ns % blk or fixed + blk * row > _VMEM_BUDGET):
             blk //= 2
         return blk
-    blk = _pick_block(ns, row)
-    while blk > 1 and fixed + blk * row > _VMEM_BUDGET:
-        blk //= 2
-    if fixed + blk * row > _VMEM_BUDGET:
-        raise ValueError(
-            f"pallas_route VMEM cost model exceeds budget at blk=1: "
-            f"{(fixed + row) / 1e6:.2f} MB (fixed {fixed / 1e6:.2f} + "
-            f"row {row / 1e6:.2f}) against the "
-            f"{_VMEM_BUDGET / 1e6:.1f} MB scoped-VMEM budget; shrink "
-            "the batch/ring configuration or use the XLA path "
-            "(WTPU_PALLAS_ROUTE=0)")
-    return blk
+    legal = [ns] + [b for b in range(128 * ((ns - 1) // 128), 0, -128)
+                    if ns % b == 0]
+    for blk in legal:
+        if fixed + blk * row <= _VMEM_BUDGET:
+            return blk
+    least = legal[-1]
+    raise ValueError(
+        f"pallas_route: no destination block obeys the TPU (8, 128) "
+        f"tiling rule within the {_VMEM_BUDGET / 1e6:.1f} MB scoped-VMEM "
+        f"budget: the count block's last dimension must be a multiple "
+        f"of 128 or all {ns} destinations, and the smallest such block "
+        f"({least}) needs {(fixed + least * row) / 1e6:.2f} MB (fixed "
+        f"{fixed / 1e6:.2f} + {least} x {row / 1e6:.2f} per "
+        "destination); use the XLA routing path (route_kernel='xla', "
+        "WTPU_PALLAS_ROUTE=0)")
 
 
 def _route_call(data_planes, src_plane, size_plane, cnt,

@@ -644,16 +644,10 @@ class ShardedRunner:
 
         spec = P("sp")
         specs = (spec,) * (3 if traced else 2)
-        # jax >= 0.6 exposes jax.shard_map (check_vma); 0.4.x only has
-        # the experimental module (check_rep).  Same semantics; the
-        # check is disabled either way (the per-shard body mixes
-        # replicated broadcast state with sharded node state).
-        if hasattr(jax, "shard_map"):
-            return jax.shard_map(wrapped, mesh=self.mesh, in_specs=specs,
-                                 out_specs=specs, check_vma=False)
-        from jax.experimental.shard_map import shard_map
-        return shard_map(wrapped, mesh=self.mesh, in_specs=specs,
-                         out_specs=specs, check_rep=False)
+        # check_vma off: the per-shard body mixes replicated broadcast
+        # state with sharded node state.
+        return jax.shard_map(wrapped, mesh=self.mesh, in_specs=specs,
+                             out_specs=specs, check_vma=False)
 
     def _metric_values(self, spec, snet):
         """Global-aggregate counter values from the sharded state —

@@ -87,8 +87,8 @@ def run_mode(mode, nodes=2048, seeds=32, max_time=6000, chunk=250,
 def compare(nodes=2048, seeds=32, max_time=6000, out_dir=".", attack=None,
             dead_ratio=None, seed_batch=None):
     if seed_batch is None and nodes >= 8192:
-        # Keep the stored-emission [R, N, N] matrix under the runtime's
-        # ~1 GB single-buffer limit (268 MB/seed at 8192).
+        # Bound the stored-emission [R, N, N] matrix at ~768 MB per
+        # microbatch (268 MB/seed at 8192).
         seed_batch = max(1, (768 << 20) // (4 * nodes * nodes))
         while seeds % seed_batch:
             seed_batch -= 1

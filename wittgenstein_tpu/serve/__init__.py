@@ -58,7 +58,8 @@ coalesced into few compiled device programs.
                 branch per site.
 """
 
-from .fleet import FleetWorker, fleet_paths, spawn_worker  # noqa: F401
+from .fleet import (FleetWorker, check_fleet_size, fleet_paths,  # noqa: F401
+                    spawn_worker, spawn_workers)
 from .instrument import Instrumentation  # noqa: F401
 from .journal import LeaseTable, SubmissionJournal  # noqa: F401
 from .registry import CompileRegistry  # noqa: F401

@@ -587,6 +587,69 @@ class FleetWorker:
 
 # ------------------------------------------------------------ subprocess
 
+def _tpu_host() -> bool:
+    """True iff this host shows TPU chips, told without initializing a
+    JAX backend (which would take the chip from the workers): libtpu
+    reaches a chip through ``/dev/accel<N>`` (TPU v4 and earlier) or
+    ``/dev/vfio/<N>`` (v5e and later; the PR 21 chip host shows
+    ``/dev/vfio/0``)."""
+    import glob
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _holds_chip() -> bool:
+    """True iff THIS process has started JAX's TPU backend.  The
+    installed JAX (0.9.0) has no public call that answers without
+    starting a backend; `xla_bridge.backends_are_initialized` is
+    private and may move in a later JAX."""
+    if "jax" not in sys.modules:
+        return False
+    import jax
+    from jax._src import xla_bridge
+    return (xla_bridge.backends_are_initialized()
+            and jax.default_backend() == "tpu")
+
+
+def check_fleet_size(workers: int, env=None) -> None:
+    """Refuse at once a fleet of `workers` worker processes that this
+    host's chips cannot hold.  A chip belongs to one process: each
+    worker loads the TPU runtime and takes every chip it sees, and the
+    runtime's lock file refuses a second process on the host.  A worker
+    spawned by a process that holds the chip claims its request, cannot
+    run it, releases it and spins until its max wall (PR 21 chip run).
+
+    The workers' `env` decides: a ``JAX_PLATFORMS`` without ``tpu``
+    (e.g. ``cpu``) never loads the TPU runtime and is not limited; one
+    naming ``tpu``, or none on a host that shows chips (`_tpu_host`),
+    admits one worker, and none while this process holds the chip."""
+    env = os.environ if env is None else env
+    plats = [p for p in env.get("JAX_PLATFORMS", "").split(",") if p]
+    if plats and "tpu" not in plats:
+        return
+    if "tpu" not in plats and not _tpu_host():
+        return
+    held = _holds_chip()
+    admitted = 0 if held else 1
+    if workers > admitted:
+        raise ValueError(
+            f"fleet: {workers} worker process(es) asked for on a TPU "
+            f"host that admits {admitted}: a chip belongs to one process "
+            "and each worker takes every chip it sees"
+            + ("; this process already holds the chip" if held else "")
+            + ". Remedy: run one worker per host, started by a process "
+            "that has not touched JAX, or give the workers "
+            "JAX_PLATFORMS=cpu")
+
+
+def spawn_workers(fleet_dir, workers: int, env=None, **kw) -> list:
+    """`workers` fleet worker subprocesses ``w0..w{n-1}`` over
+    `fleet_dir` (keyword options as `spawn_worker`), after
+    `check_fleet_size` admits them."""
+    check_fleet_size(int(workers), env)
+    return [spawn_worker(fleet_dir, f"w{i}", env=env, **kw)
+            for i in range(int(workers))]
+
+
 def spawn_worker(fleet_dir, worker_id: str, *, lease_ttl_s: float = 10.0,
                  idle_exit_s: float | None = 3.0, max_wall_s=None,
                  poll_s: float = 0.25, dedup: bool = True, env=None,
