@@ -205,8 +205,8 @@ def test_exporters_csv_perfetto_bench_block():
 
     trace = to_perfetto(frame)
     evs = trace["traceEvents"]
-    # the conventions tools/tpu_profile.collect_trace parses: metadata
-    # process_name + "X"/"C" events with ts/dur in us
+    # Chrome-trace conventions: metadata process_name + "X"/"C"
+    # events with ts/dur in us
     assert any(e.get("ph") == "M" and e.get("name") == "process_name"
                for e in evs)
     xs = [e for e in evs if e.get("ph") == "X"]

@@ -35,7 +35,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .network import (_route_unicast, check_chunk_config, fast_forward_ok,
+from .network import (STAGE_BIN, STAGE_INBOX, STAGE_PROTOCOL, STAGE_ROUTE,
+                      _route_unicast, check_chunk_config, fast_forward_ok,
                       superstep_ok)
 from .protocol import FAR_FUTURE
 from .state import EngineConfig, Inbox, NetState
@@ -191,21 +192,23 @@ def step_kms_batched(protocol, net: NetState, pstate, k: int,
                          f"{len(hints_k)}")
     r = net.box_count.shape[0]
     t = net.time[0]
-    # Chaos-plane hook (see network.step_kms): one stateless window-entry
-    # application; the [N] fault vectors broadcast over the [R, N] node
-    # leaves, and K-aligned transitions keep the window state constant.
-    af = getattr(protocol, "apply_faults", None)
-    if af is not None:
-        net = af(net, t)
+    with jax.named_scope(STAGE_INBOX):
+        # Chaos-plane hook (see network.step_kms): one stateless
+        # window-entry application; the [N] fault vectors broadcast over
+        # the [R, N] node leaves, and K-aligned transitions keep the
+        # window state constant.
+        af = getattr(protocol, "apply_faults", None)
+        if af is not None:
+            net = af(net, t)
 
-    inboxes = []
-    for i in range(k):
-        # `t + i if i else t`: keeps the i == 0 trace free of a dead
-        # `t + 0` eqn (the jaxpr_eqns budgets pin the K == 2 program
-        # at exactly the historical step_2ms_batched op count).
-        ib, nodes = _batched_inbox(cfg, net, t + i if i else t)
-        net = net.replace(nodes=nodes)
-        inboxes.append(ib)
+        inboxes = []
+        for i in range(k):
+            # `t + i if i else t`: keeps the i == 0 trace free of a dead
+            # `t + 0` eqn (the jaxpr_eqns budgets pin the K == 2 program
+            # at exactly the historical step_2ms_batched op count).
+            ib, nodes = _batched_inbox(cfg, net, t + i if i else t)
+            net = net.replace(nodes=nodes)
+            inboxes.append(ib)
 
     # Order every later plane WRITE after all K inbox READS by threading
     # the planes through one optimization_barrier with the inbox values.
@@ -233,44 +236,48 @@ def step_kms_batched(protocol, net: NetState, pstate, k: int,
         return protocol.step(ps, nodes_r, inbox_r, tt, key, hints=hints)
 
     outs = []
-    for i in range(k):
-        hint = None if hints_k is None else hints_k[i]
-        pstate, nodes, out = jax.vmap(
-            lambda ps, nd, ib, sd, tt=(t + i if i else t), hh=hint:
-            pstep(ps, nd, ib, sd, tt, hh))(
-            pstate, net.nodes, inboxes[i], net.seed)
-        net = net.replace(nodes=nodes)
-        outs.append(out)
+    with jax.named_scope(STAGE_PROTOCOL):
+        for i in range(k):
+            hint = None if hints_k is None else hints_k[i]
+            pstate, nodes, out = jax.vmap(
+                lambda ps, nd, ib, sd, tt=(t + i if i else t), hh=hint:
+                pstep(ps, nd, ib, sd, tt, hh))(
+                pstate, net.nodes, inboxes[i], net.seed)
+            net = net.replace(nodes=nodes)
+            outs.append(out)
 
     h = t % cfg.horizon
     n = cfg.n
-    net = net.replace(box_count=jax.lax.dynamic_update_slice(
-        net.box_count, jnp.zeros((r, k, n), jnp.int32), (0, h, 0)))
+    with jax.named_scope(STAGE_BIN):
+        net = net.replace(box_count=jax.lax.dynamic_update_slice(
+            net.box_count, jnp.zeros((r, k, n), jnp.int32), (0, h, 0)))
 
     # Routing per seed (vmapped — elementwise + latency model), then ONE
     # folded bin for all K ms across all seeds.
     def route(net_r, out_r, tt):
         return _route_unicast(cfg, model, net_r, out_r, tt)
 
-    batches = []
-    for i, out in enumerate(outs):
-        net, b, _ = jax.vmap(
-            lambda nr, orr, tt=(t + i if i else t):
-            route(nr, orr, tt))(net, out)
-        batches.append(b)
-    terms = [jnp.sum(b[6], axis=1) for b in batches]
-    n_clamped = terms[0]
-    for tm in terms[1:]:
-        n_clamped = n_clamped + tm
-    n_clamped = n_clamped.astype(jnp.int32)
-    src = jnp.concatenate([b[0] for b in batches], axis=1)
-    dest = jnp.concatenate([b[1] for b in batches], axis=1)
-    arrival = jnp.concatenate([b[2] for b in batches], axis=1)
-    payload = jnp.concatenate([b[3] for b in batches], axis=1)
-    size = jnp.concatenate([b[4] for b in batches], axis=1)
-    valid = jnp.concatenate([b[5] for b in batches], axis=1)
-    net, n_dropped = _batched_bin(cfg, net, t, src, dest, arrival,
-                                  payload, size, valid)
+    with jax.named_scope(STAGE_ROUTE):
+        batches = []
+        for i, out in enumerate(outs):
+            net, b, _ = jax.vmap(
+                lambda nr, orr, tt=(t + i if i else t):
+                route(nr, orr, tt))(net, out)
+            batches.append(b)
+        terms = [jnp.sum(b[6], axis=1) for b in batches]
+        n_clamped = terms[0]
+        for tm in terms[1:]:
+            n_clamped = n_clamped + tm
+        n_clamped = n_clamped.astype(jnp.int32)
+    with jax.named_scope(STAGE_BIN):
+        src = jnp.concatenate([b[0] for b in batches], axis=1)
+        dest = jnp.concatenate([b[1] for b in batches], axis=1)
+        arrival = jnp.concatenate([b[2] for b in batches], axis=1)
+        payload = jnp.concatenate([b[3] for b in batches], axis=1)
+        size = jnp.concatenate([b[4] for b in batches], axis=1)
+        valid = jnp.concatenate([b[5] for b in batches], axis=1)
+        net, n_dropped = _batched_bin(cfg, net, t, src, dest, arrival,
+                                      payload, size, valid)
     net = net.replace(dropped=net.dropped + n_dropped,
                       clamped=net.clamped + n_clamped,
                       time=net.time + k)

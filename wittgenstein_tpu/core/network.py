@@ -40,6 +40,15 @@ from .latency import full_latency, latency_floor_ms
 from .protocol import FAR_FUTURE
 from .state import EngineConfig, Inbox, NetState, Outbox
 
+#: The engine's stages as `jax.named_scope`s.  A scope is compile-time
+#: metadata (each op's `op_name`; no jaxpr equation), so a device trace
+#: can be reduced by stage (`benchmark/stages.py`).  What no scope holds
+#: (loop control, the copies XLA inserts for the scan carry) is the carry.
+STAGE_INBOX = "engine.inbox"          # ring-row reads, delivery masks
+STAGE_PROTOCOL = "protocol.step"      # the protocol's own step
+STAGE_ROUTE = "engine.route"          # sender counters, latency, validity
+STAGE_BIN = "engine.bin"              # slot clear, sort, rank, scatter
+
 
 def _retire_broadcasts(cfg: EngineConfig, net: NetState, t) -> NetState:
     # A broadcast's last possible arrival is bc_time + horizon - 1.
@@ -397,19 +406,21 @@ def enqueue_unicast(cfg: EngineConfig, model, net: NetState, out: Outbox, t):
     (Network.java:449-487).  Here: one latency draw per message, then the
     sort-based binning of `_bin_into_ring`.
     """
-    net, batch, arrival_raw = _route_unicast(cfg, model, net, out, t)
+    with jax.named_scope(STAGE_ROUTE):
+        net, batch, arrival_raw = _route_unicast(cfg, model, net, out, t)
     src, dest_c, arrival, payload, size, valid, far = batch
-    if cfg.spill_cap > 0:
-        net = _park_in_spill(cfg, net, src, dest_c, arrival_raw,
-                             payload, size, far)
-        ring_valid = valid & ~far
-        n_clamped = jnp.asarray(0, jnp.int32)
-    else:
-        ring_valid = valid
-        n_clamped = jnp.sum(far).astype(jnp.int32)
+    with jax.named_scope(STAGE_BIN):
+        if cfg.spill_cap > 0:
+            net = _park_in_spill(cfg, net, src, dest_c, arrival_raw,
+                                 payload, size, far)
+            ring_valid = valid & ~far
+            n_clamped = jnp.asarray(0, jnp.int32)
+        else:
+            ring_valid = valid
+            n_clamped = jnp.sum(far).astype(jnp.int32)
 
-    net, n_dropped = _bin_into_ring(cfg, net, t, src, dest_c, arrival,
-                                    payload, size, ring_valid)
+        net, n_dropped = _bin_into_ring(cfg, net, t, src, dest_c, arrival,
+                                        payload, size, ring_valid)
     return net.replace(dropped=net.dropped + n_dropped,
                        clamped=net.clamped + n_clamped)
 
@@ -475,32 +486,38 @@ def step_ms(protocol, net: NetState, pstate, hints=None, tap=None):
     # zero extra operations (the zero-cost lints stay pinned).
     af = getattr(protocol, "apply_faults", None)
     if af is not None:
-        net = af(net, t)
+        with jax.named_scope(STAGE_INBOX):
+            net = af(net, t)
     if tap is not None:
         tap(t, net, None)
-    if cfg.bcast_slots > 0:
-        net = _retire_broadcasts(cfg, net, t)
-    if cfg.spill_cap > 0:
-        net = _drain_spill(cfg, net, t)
-    inbox, nodes, bc_clamped = build_inbox(cfg, model, net, t)
-    net = net.replace(nodes=nodes, clamped=net.clamped + bc_clamped)
+    with jax.named_scope(STAGE_INBOX):
+        if cfg.bcast_slots > 0:
+            net = _retire_broadcasts(cfg, net, t)
+        if cfg.spill_cap > 0:
+            net = _drain_spill(cfg, net, t)
+        inbox, nodes, bc_clamped = build_inbox(cfg, model, net, t)
+        net = net.replace(nodes=nodes, clamped=net.clamped + bc_clamped)
 
     key = jax.random.fold_in(jax.random.PRNGKey(net.seed), t)
-    if hints is None:
-        pstate, nodes, out = protocol.step(pstate, net.nodes, inbox, t, key)
-    else:
-        pstate, nodes, out = protocol.step(pstate, net.nodes, inbox, t, key,
-                                           hints=hints)
+    with jax.named_scope(STAGE_PROTOCOL):
+        if hints is None:
+            pstate, nodes, out = protocol.step(pstate, net.nodes, inbox, t,
+                                               key)
+        else:
+            pstate, nodes, out = protocol.step(pstate, net.nodes, inbox, t,
+                                               key, hints=hints)
     net = net.replace(nodes=nodes)
     if tap is not None:
         tap(t, net, out)
 
     # Clear the consumed slot, then route new sends (their arrivals are
     # >= t+2, so they can never land in the slot just cleared).
-    net = net.replace(box_count=net.box_count.at[t % cfg.horizon].set(0))
+    with jax.named_scope(STAGE_BIN):
+        net = net.replace(box_count=net.box_count.at[t % cfg.horizon].set(0))
     net = enqueue_unicast(cfg, model, net, out, t)
     if cfg.bcast_slots > 0:
-        net = enqueue_broadcast(cfg, net, out, t)
+        with jax.named_scope(STAGE_ROUTE):
+            net = enqueue_broadcast(cfg, net, out, t)
     return net.replace(time=t + 1), pstate
 
 
@@ -567,7 +584,8 @@ def step_kms(protocol, net: NetState, pstate, k: int, hints_k=None,
     # taps) sees exactly what the per-ms engine would.
     af = getattr(protocol, "apply_faults", None)
     if af is not None:
-        net = af(net, t)
+        with jax.named_scope(STAGE_INBOX):
+            net = af(net, t)
     # Entry tap for the window's FIRST ms: before retire, matching the
     # per-ms path's observation point.  Later ms tap inside the loop —
     # their ring rows are untouched until the window's deferred clear,
@@ -577,87 +595,95 @@ def step_kms(protocol, net: NetState, pstate, k: int, hints_k=None,
     # trace equality).
     if tap is not None:
         tap(t, net, None)
-    if cfg.bcast_slots > 0:
-        net = _retire_broadcasts(cfg, net, t)
+    with jax.named_scope(STAGE_INBOX):
+        if cfg.bcast_slots > 0:
+            net = _retire_broadcasts(cfg, net, t)
 
-    # All K unicast slices + their receive counters up-front (counters
-    # are write-only to the protocol step, so the early bump is
-    # unobservable — the step_2ms precedent).
-    uc_data, uc_src, uc_size, uc_valid = _unicast_inbox_window(
-        cfg, net, t, k)
-    recv = jnp.sum(uc_valid, axis=(0, 2)).astype(jnp.int32)
-    rbytes = jnp.sum(jnp.where(uc_valid, uc_size, 0),
-                     axis=(0, 2)).astype(jnp.int32)
-    net = net.replace(nodes=net.nodes.replace(
-        msg_received=net.nodes.msg_received + recv,
-        bytes_received=net.nodes.bytes_received + rbytes))
+        # All K unicast slices + their receive counters up-front
+        # (counters are write-only to the protocol step, so the early
+        # bump is unobservable — the step_2ms precedent).
+        uc_data, uc_src, uc_size, uc_valid = _unicast_inbox_window(
+            cfg, net, t, k)
+        recv = jnp.sum(uc_valid, axis=(0, 2)).astype(jnp.int32)
+        rbytes = jnp.sum(jnp.where(uc_valid, uc_size, 0),
+                         axis=(0, 2)).astype(jnp.int32)
+        net = net.replace(nodes=net.nodes.replace(
+            msg_received=net.nodes.msg_received + recv,
+            bytes_received=net.nodes.bytes_received + rbytes))
 
     outs = []
     for i in range(k):
         ti = t + i if i else t      # no dead `t + 0` eqn in the trace
         if i > 0 and tap is not None:
             tap(ti, net, None)
-        if i > 0 and cfg.bcast_slots > 0:
-            net = _retire_broadcasts(cfg, net, ti)
-        if cfg.bcast_slots > 0:
-            bc_data, bc_src, bc_size, bc_valid, n_cl = _bcast_inbox(
-                cfg, model, net, ti)
-            recv_b = jnp.sum(bc_valid, 1).astype(jnp.int32)
-            rb_b = jnp.sum(jnp.where(bc_valid, bc_size, 0),
-                           1).astype(jnp.int32)
-            net = net.replace(
-                nodes=net.nodes.replace(
-                    msg_received=net.nodes.msg_received + recv_b,
-                    bytes_received=net.nodes.bytes_received + rb_b),
-                clamped=net.clamped + n_cl)
-            inbox = Inbox(
-                data=jnp.concatenate([uc_data[i], bc_data], axis=1),
-                src=jnp.concatenate([uc_src[i], bc_src], axis=1),
-                valid=jnp.concatenate([uc_valid[i], bc_valid], axis=1))
-        else:
-            inbox = Inbox(data=uc_data[i], src=uc_src[i],
-                          valid=uc_valid[i])
+        with jax.named_scope(STAGE_INBOX):
+            if i > 0 and cfg.bcast_slots > 0:
+                net = _retire_broadcasts(cfg, net, ti)
+            if cfg.bcast_slots > 0:
+                bc_data, bc_src, bc_size, bc_valid, n_cl = _bcast_inbox(
+                    cfg, model, net, ti)
+                recv_b = jnp.sum(bc_valid, 1).astype(jnp.int32)
+                rb_b = jnp.sum(jnp.where(bc_valid, bc_size, 0),
+                               1).astype(jnp.int32)
+                net = net.replace(
+                    nodes=net.nodes.replace(
+                        msg_received=net.nodes.msg_received + recv_b,
+                        bytes_received=net.nodes.bytes_received + rb_b),
+                    clamped=net.clamped + n_cl)
+                inbox = Inbox(
+                    data=jnp.concatenate([uc_data[i], bc_data], axis=1),
+                    src=jnp.concatenate([uc_src[i], bc_src], axis=1),
+                    valid=jnp.concatenate([uc_valid[i], bc_valid], axis=1))
+            else:
+                inbox = Inbox(data=uc_data[i], src=uc_src[i],
+                              valid=uc_valid[i])
         key = jax.random.fold_in(jax.random.PRNGKey(net.seed), ti)
         h_i = None if hints_k is None else hints_k[i]
-        if h_i is None:
-            pstate, nodes, out = protocol.step(pstate, net.nodes, inbox,
-                                               ti, key)
-        else:
-            pstate, nodes, out = protocol.step(pstate, net.nodes, inbox,
-                                               ti, key, hints=h_i)
+        with jax.named_scope(STAGE_PROTOCOL):
+            if h_i is None:
+                pstate, nodes, out = protocol.step(pstate, net.nodes,
+                                                   inbox, ti, key)
+            else:
+                pstate, nodes, out = protocol.step(pstate, net.nodes,
+                                                   inbox, ti, key,
+                                                   hints=h_i)
         net = net.replace(nodes=nodes)
         outs.append(out)
         if tap is not None:
             tap(ti, net, out)
         if cfg.bcast_slots > 0:
-            net = enqueue_broadcast(cfg, net, out, ti)
+            with jax.named_scope(STAGE_ROUTE):
+                net = enqueue_broadcast(cfg, net, out, ti)
 
     # Clear all K consumed slots in one K-row window (h ≡ 0 mod K and
     # K | horizon: no wrap).
     h = t % cfg.horizon
-    net = net.replace(box_count=jax.lax.dynamic_update_slice(
-        net.box_count, jnp.zeros((k, cfg.n), jnp.int32), (h, 0)))
+    with jax.named_scope(STAGE_BIN):
+        net = net.replace(box_count=jax.lax.dynamic_update_slice(
+            net.box_count, jnp.zeros((k, cfg.n), jnp.int32), (h, 0)))
 
     # Route every outbox (latency draws keyed on each step's own ms),
     # then bin them together: one sort + one scatter pass for K ms.
-    batches = []
-    for i, out in enumerate(outs):
-        net, b, _ = _route_unicast(cfg, model, net, out,
-                                   t + i if i else t)
-        batches.append(b)
-    terms = [jnp.sum(b[6]) for b in batches]
-    n_clamped = terms[0]
-    for tm in terms[1:]:
-        n_clamped = n_clamped + tm
-    n_clamped = n_clamped.astype(jnp.int32)
-    src = jnp.concatenate([b[0] for b in batches])
-    dest = jnp.concatenate([b[1] for b in batches])
-    arrival = jnp.concatenate([b[2] for b in batches])
-    payload = jnp.concatenate([b[3] for b in batches])
-    size = jnp.concatenate([b[4] for b in batches])
-    valid = jnp.concatenate([b[5] for b in batches])
-    net, n_dropped = _bin_into_ring(cfg, net, t, src, dest, arrival,
-                                    payload, size, valid)
+    with jax.named_scope(STAGE_ROUTE):
+        batches = []
+        for i, out in enumerate(outs):
+            net, b, _ = _route_unicast(cfg, model, net, out,
+                                       t + i if i else t)
+            batches.append(b)
+        terms = [jnp.sum(b[6]) for b in batches]
+        n_clamped = terms[0]
+        for tm in terms[1:]:
+            n_clamped = n_clamped + tm
+        n_clamped = n_clamped.astype(jnp.int32)
+    with jax.named_scope(STAGE_BIN):
+        src = jnp.concatenate([b[0] for b in batches])
+        dest = jnp.concatenate([b[1] for b in batches])
+        arrival = jnp.concatenate([b[2] for b in batches])
+        payload = jnp.concatenate([b[3] for b in batches])
+        size = jnp.concatenate([b[4] for b in batches])
+        valid = jnp.concatenate([b[5] for b in batches])
+        net, n_dropped = _bin_into_ring(cfg, net, t, src, dest, arrival,
+                                        payload, size, valid)
     net = net.replace(dropped=net.dropped + n_dropped,
                       clamped=net.clamped + n_clamped)
     return net.replace(time=t + k), pstate

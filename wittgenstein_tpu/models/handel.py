@@ -422,13 +422,19 @@ class Handel(LevelMixin, StaticScheduleMixin):
         subm = self._subword_masks(ids)
         hi = ids >> 5
 
-        p = self._receive(p, nodes, inbox, t)
+        # Sub-scopes of the engine's `protocol.step` stage (core/network).
+        with jax.named_scope("handel.receive"):
+            p = self._receive(p, nodes, inbox, t)
         if h.get("verify", True):
-            p, nodes = self._apply_pending(p, nodes, t, onehot, subm, hi)
-            p = self._pick_verification(p, nodes, t, active, onehot,
-                                        subm, hi)
-        p, out = self._disseminate(p, nodes, t, active, onehot, subm, hi,
-                                   periodic=h.get("periodic", True))
+            with jax.named_scope("handel.apply"):
+                p, nodes = self._apply_pending(p, nodes, t, onehot, subm,
+                                               hi)
+            with jax.named_scope("handel.verify"):
+                p = self._pick_verification(p, nodes, t, active, onehot,
+                                            subm, hi)
+        with jax.named_scope("handel.disseminate"):
+            p, out = self._disseminate(p, nodes, t, active, onehot, subm,
+                                       hi, periodic=h.get("periodic", True))
         return p, nodes, out
 
     # -- receive: queue incoming aggregates (onNewSig, Handel.java:753-786)

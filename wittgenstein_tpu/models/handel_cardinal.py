@@ -272,12 +272,17 @@ class HandelCardinal(LevelMixin, StaticScheduleMixin):
     def step(self, p: HandelCardinalState, nodes, inbox, t, key, hints=None):
         h = hints or {}
         active = (~nodes.down) & (t >= p.start_at + 1)
-        p = self._receive(p, nodes, inbox, t)
+        # Sub-scopes of the engine's `protocol.step` stage (core/network).
+        with jax.named_scope("handel.receive"):
+            p = self._receive(p, nodes, inbox, t)
         if h.get("verify", True):
-            p, nodes = self._apply_pending(p, nodes, t)
-            p = self._pick_verification(p, nodes, t, active)
-        p, out = self._disseminate(p, nodes, t, active,
-                                   periodic=h.get("periodic", True))
+            with jax.named_scope("handel.apply"):
+                p, nodes = self._apply_pending(p, nodes, t)
+            with jax.named_scope("handel.verify"):
+                p = self._pick_verification(p, nodes, t, active)
+        with jax.named_scope("handel.disseminate"):
+            p, out = self._disseminate(p, nodes, t, active,
+                                       periodic=h.get("periodic", True))
         return p, nodes, out
 
     # -- receive: queue incoming counts (onNewSig, Handel.java:753-786)

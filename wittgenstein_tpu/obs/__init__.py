@@ -17,7 +17,7 @@ only AFTER the chunk returns, so everything that happens *during* a
   host side (`export`): a `MetricsFrame` wraps the fetched series and
   exports (a) a ProgressPerTime-style CSV via `tools/csvf`, (b) a
   Chrome-trace/Perfetto JSON that loads on one timeline with the XLA
-  op traces `tools/tpu_profile.py` parses, and (c) the structured
+  op traces of a `jax.profiler` capture, and (c) the structured
   ``engine_metrics`` block `bench.py` embeds in its JSON line.
 
 Two hard invariants (tests/test_obs.py, analysis `metrics_zero_cost`):

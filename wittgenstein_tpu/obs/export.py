@@ -9,10 +9,10 @@ per-interval deltas for the cumulative counters, and run totals.
 Exporters:
   * `to_progress_csv` — the ProgressPerTime-style table
     (ProgressPerTime.java:53-149) via `tools/csvf.CSVFormatter`;
-  * `to_perfetto` — Chrome-trace/Perfetto JSON using the same event
-    conventions `tools/tpu_profile.py` parses (`process_name` metadata,
-    "X" slices, "C" counter tracks), so engine intervals and XLA op
-    traces load on one Perfetto timeline (the engine lane's clock is
+  * `to_perfetto` — Chrome-trace/Perfetto JSON in the Chrome-trace
+    event conventions (`process_name` metadata, "X" slices, "C"
+    counter tracks), so engine intervals and XLA op traces load on one
+    Perfetto timeline (the engine lane's clock is
     SIMULATED ms, scaled 1 sim-ms -> 1 trace-ms);
   * `engine_metrics_block` — the structured dict `bench.py` /
     `tools/bench_suite.py` embed as ``engine_metrics`` in `BENCH_*.json`
@@ -164,10 +164,10 @@ def to_perfetto(frame: MetricsFrame, path: str | None = None,
                 name: str = "wtpu engine") -> dict:
     """Chrome-trace JSON for the engine's interval series.
 
-    Event conventions match what `tools/tpu_profile.collect_trace`
-    parses: `process_name`/`thread_name` "M" metadata, "X" duration
-    slices (one per executed interval, args = that row's counters) and
-    "C" counter events per enabled series.  Timestamps are
+    Chrome-trace event conventions: `process_name`/`thread_name` "M"
+    metadata, "X" duration slices (one per executed interval, args =
+    that row's counters) and "C" counter events per enabled series.
+    Timestamps are
     ``1 sim-ms -> 1000 trace-us`` so the sim clock reads in ms in the
     UI.  `path` (optional) writes the JSON; a ``.gz`` suffix gzips it.
     """
@@ -229,7 +229,7 @@ def trace_to_perfetto(frame, path: str | None = None,
     Same conventions and clock as `to_perfetto` (1 sim-ms -> 1000
     trace-us, `process_name`/`thread_name` "M" metadata, "X" slices),
     so a flight-recorder capture, the metrics interval lane and the XLA
-    op traces `tools/tpu_profile.py` parses all load on ONE Perfetto
+    op traces of a `jax.profiler` capture all load on ONE Perfetto
     timeline.  Track assignment: sends/drops/spill parks on the SOURCE
     node's track, deliveries/unparks on the DESTINATION's,
     node_down/node_up on the node's own; engine-global events

@@ -105,6 +105,15 @@ SEARCH_COUNTERS = {
     "search_probes_total": "wtpu_search_probes_total",
 }
 
+#: registry compile counter (`CompileRegistry.compile_stats`) ->
+#: exposed counter name
+COMPILE_COUNTERS = {
+    "trace_s": "wtpu_compile_trace_seconds_total",
+    "compile_s": "wtpu_compile_backend_seconds_total",
+    "cache_hits": "wtpu_compile_cache_hits_total",
+    "cache_misses": "wtpu_compile_cache_misses_total",
+}
+
 
 class Instrumentation:
     """One handle bundling the span recorder and the metrics registry.
@@ -172,6 +181,9 @@ def refresh_scheduler_metrics(metrics, sch) -> None:
     if reg is not None:
         metrics.set_gauge("wtpu_registry_hits", reg.hits)
         metrics.set_gauge("wtpu_registry_misses", reg.misses)
+        stats = reg.compile_stats()
+        for key, name in COMPILE_COUNTERS.items():
+            metrics.set_counter(name, stats[key])
 
 
 def refresh_fleet_counters(metrics, counters) -> None:

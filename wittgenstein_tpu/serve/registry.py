@@ -19,6 +19,20 @@ Hit/miss counters are exported through the obs block conventions
 ``wtpu_registry_{hits,misses}`` gauges into ``GET /w/batch/metrics``
 (serve/instrument.refresh_scheduler_metrics).
 
+Set-up is counted where it happens: one process-wide `jax.monitoring`
+listener (`compile_counters`, installed once) sums the seconds JAX
+spends tracing and lowering (`trace_s`: where the protocol's lazy
+tables are built) and compiling or loading from the persistent cache
+(`compile_s`), and counts that cache's hits and misses.
+`compile_stats()` reports them since the registry was made, in
+`registry_block()` and as ``wtpu_compile_*`` counters in
+``GET /w/batch/metrics``.  They stay out of `stats()`, which request
+artifacts carry: wall seconds and cache state are not results.
+
+Every chunk program is jitted under a stable name that says its engine
+and plane (`chunk_batched`, `chunk_vmapped_metrics`, ...), so its
+module is ``jit_chunk_batched`` in every compile and device trace.
+
 With a `ProgramCatalog` attached (``catalog=``, default None = zero
 cost beyond one is-None branch), every cold build returns an
 `obs.programs.CatalogProgram` instead of the bare jit wrapper: the
@@ -30,10 +44,95 @@ predictions staged here via `record_build`).
 
 from __future__ import annotations
 
+import bisect
+import threading
+
 import jax
 
 from ..core.harness import enable_persistent_cache
 from .spec import ScenarioSpec
+
+#: jax.monitoring events the compile counters read.  Trace and lower
+#: spans nest (an inner jit is traced inside its caller), so each
+#: group's seconds are the UNION of its spans.  The backend compile span
+#: encloses the persistent cache's read: on a hit, `compile_s` is the
+#: load.
+TRACE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                "/jax/core/compile/jaxpr_to_mlir_module_duration")
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"   # entry written
+#: separate spans a group keeps for a later, enclosing span to merge
+#: with; older ones are only counted.  A span reported after more than
+#: this many separate spans it encloses counts the oldest of them twice
+#: (tracing a Handel chunk program encloses about 1,900).
+KEEP_SPANS = 1 << 16
+
+
+class CompileCounters:
+    """Set-up work of this process so far, from JAX's monitoring events,
+    as running totals: a reader takes the difference of two `totals`
+    readings, and the memory stays bounded however long the process
+    lives."""
+
+    _LOCK_OWNS = {"_mu": ("_spans", "_totals")}
+
+    def __init__(self):
+        self._mu = threading.Lock()
+        #: the latest disjoint [start, end] spans per group, sorted
+        self._spans = {"trace_s": [], "compile_s": []}
+        self._totals = {"trace_s": 0.0, "compile_s": 0.0,
+                        "cache_hits": 0, "cache_misses": 0}
+
+    def install(self):
+        from jax import monitoring
+        monitoring.register_event_time_span_listener(self._on_span)
+        monitoring.register_event_listener(self._on_event)
+
+    def _on_span(self, event, start, end, **_):
+        group = ("trace_s" if event in TRACE_EVENTS else
+                 "compile_s" if event == COMPILE_EVENT else None)
+        if group is None:
+            return
+        with self._mu:
+            spans = self._spans[group]
+            lo = bisect.bisect_left(spans, start, key=lambda iv: iv[1])
+            hi = bisect.bisect_right(spans, end, key=lambda iv: iv[0])
+            if lo < hi:
+                start = min(start, spans[lo][0])
+                end = max(end, spans[hi - 1][1])
+            self._totals[group] += (end - start) - sum(
+                b - a for a, b in spans[lo:hi])
+            spans[lo:hi] = [(start, end)]
+            del spans[:-KEEP_SPANS]
+
+    def _on_event(self, event, **_):
+        name = {CACHE_HIT_EVENT: "cache_hits",
+                CACHE_MISS_EVENT: "cache_misses"}.get(event)
+        if name is not None:
+            with self._mu:
+                self._totals[name] += 1
+
+    def totals(self) -> dict:
+        """Seconds and counts since the listener was installed:
+        ``trace_s``, ``compile_s``, ``cache_hits``, ``cache_misses``."""
+        with self._mu:
+            return dict(self._totals)
+
+
+_COUNTERS: CompileCounters | None = None
+_COUNTERS_MU = threading.Lock()
+
+
+def compile_counters() -> CompileCounters:
+    """The process's `CompileCounters`, its listener installed on the
+    first call."""
+    global _COUNTERS
+    with _COUNTERS_MU:
+        if _COUNTERS is None:
+            _COUNTERS = CompileCounters()
+            _COUNTERS.install()
+        return _COUNTERS
 
 
 class CompileRegistry:
@@ -49,6 +148,8 @@ class CompileRegistry:
         self._programs: dict = {}
         self.hits = 0
         self.misses = 0
+        self.compiles = compile_counters()
+        self._compiles0 = self.compiles.totals()
 
     # ------------------------------------------------------------- lookup
 
@@ -153,6 +254,8 @@ class CompileRegistry:
         else:
             raise ValueError(f"unknown obs plane {plane!r}; known: "
                              "metrics trace audit (or None)")
+        base.__name__ = base.__qualname__ = (
+            f"chunk_{eng}" if plane is None else f"chunk_{eng}_{plane}")
         # Pin the spec's routing-kernel selection around every call —
         # tracing happens inside the FIRST call, and a process-level
         # WTPU_PALLAS_ROUTE must never flip what this compile key
@@ -177,12 +280,18 @@ class CompileRegistry:
                 "misses": self.misses,
                 "persistent_cache": self.cache_dir or "off"}
 
+    def compile_stats(self) -> dict:
+        """The process's trace and compile work since this registry was
+        made (`CompileCounters.totals`)."""
+        now = self.compiles.totals()
+        return {k: now[k] - self._compiles0[k] for k in now}
+
     def registry_block(self, extra: dict | None = None) -> dict:
         """The ``registry`` block for bench JSON / service status
         (schema: BENCH_NOTES.md r11) — the warm/cold story of every
         submit, in the same one-flat-dict convention as
         `engine_metrics_block`."""
-        out = self.stats()
+        out = {**self.stats(), **self.compile_stats()}
         if extra:
             out.update(extra)
         return out
