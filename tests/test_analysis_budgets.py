@@ -33,7 +33,24 @@ def test_handel_while_body_plane_copies_le_2():
         "per-leaf attribution.")
 
 
+def test_phase_specialized_plane_copies_per_body_le_2():
+    """The request plane's phase-specialized Handel program runs two
+    scan bodies (the block with its lead superstep, and the inner scan
+    of the merged rest); each copies no more ring planes than the
+    plain program's one body."""
+    target = get_target("Handel+phase")
+    from wittgenstein_tpu.analysis import hlo
+    assert len(hlo.scan_bodies(target.hlo_text)) == 2
+    metrics = rules_carry.measure(target)
+    assert metrics["body_plane_copies"] <= 2
+    assert metrics["body_boxcount_copies"] <= 2
+
+
 def test_checked_in_budget_encodes_the_gate():
     with open(framework.BUDGETS_PATH) as f:
         budgets = json.load(f)
     assert budgets["carry_copy"]["Handel"]["plane_copies"] <= 2
+    plain = budgets["carry_copy"]["Handel"]
+    phase = budgets["carry_copy"]["Handel+phase"]
+    for m in ("body_plane_copies", "body_boxcount_copies"):
+        assert phase[m] <= plain[m] <= 2

@@ -23,10 +23,9 @@ def _trees_equal(a, b):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-def _run_both(proto, ms, seeds=3, t0_mod=None, plane_barrier=True):
-    ref = jax.jit(jax.vmap(scan_chunk(proto, ms, t0_mod=t0_mod,
-                                      superstep=2)))
-    bat = jax.jit(scan_chunk_batched(proto, ms, t0_mod=t0_mod,
+def _run_both(proto, ms, seeds=3, plane_barrier=True):
+    ref = jax.jit(jax.vmap(scan_chunk(proto, ms, superstep=2)))
+    bat = jax.jit(scan_chunk_batched(proto, ms,
                                      plane_barrier=plane_barrier))
     sd = jnp.arange(seeds, dtype=jnp.int32)
     nets, ps = jax.vmap(proto.init)(sd)
@@ -46,22 +45,97 @@ def test_batched_matches_vmapped_plain():
     assert int(np.asarray(ps.sigs_checked).sum()) > 0
 
 
-@pytest.mark.slow
-def test_batched_matches_vmapped_phase_specialized():
-    proto = Handel(node_count=64, threshold=56, nodes_down=6,
-                   pairing_time=4, dissemination_period_ms=20,
-                   level_wait_time=50, fast_path=10)
-    a, b = _run_both(proto, 120, t0_mod=0)
-    _trees_equal(a, b)
+_HANDEL = dict(node_count=64, threshold=56, nodes_down=6, pairing_time=4,
+               dissemination_period_ms=20, level_wait_time=50,
+               fast_path=10)
+#: pairing 3, period 10: lcm 30, so the block is no power of two
+_ODD_LCM = dict(node_count=64, threshold=60, nodes_down=0, pairing_time=3,
+                dissemination_period_ms=10, level_wait_time=30,
+                fast_path=4)
+
+#: (Handel parameters, entry residue t0_mod, chunk: a multiple of the
+#: schedule block, past the first level wait so that later levels'
+#: periodic dissemination and verification are compared too).  At
+#: residue 10 periodic dissemination falls in the merged rest (lead:
+#: no verify, no periodic), not in the lead.
+PHASE_CASES = {
+    "exact": (_HANDEL, 0, 120),
+    "cardinal": ({**_HANDEL, "mode": "cardinal"}, 0, 80),
+    "cardinal-residue10": ({**_HANDEL, "mode": "cardinal"}, 10, 80),
+    "cardinal-lcm30": ({**_ODD_LCM, "mode": "cardinal"}, 0, 60),
+}
 
 
-@pytest.mark.slow
-def test_batched_matches_vmapped_cardinal():
-    proto = Handel(node_count=64, threshold=56, nodes_down=6,
-                   pairing_time=4, dissemination_period_ms=20,
-                   fast_path=10, mode="cardinal")
-    a, b = _run_both(proto, 80, t0_mod=0)
-    _trees_equal(a, b)
+@pytest.mark.parametrize("case", sorted(PHASE_CASES))
+def test_batched_matches_vmapped_phase_specialized(case):
+    """The compact phase-specialized batched scan (one lead superstep
+    and an inner scan of the OR-merged rest per schedule block) is
+    bit-identical to the plain batched scan and to the vmapped
+    `scan_chunk(t0_mod)`, whose block is fully unrolled — entered at
+    ``time % lcm == t0_mod`` over two to six blocks."""
+    params, t0_mod, ms = PHASE_CASES[case]
+    proto = Handel(**params)
+    assert ms % proto.schedule_lcm == 0 and ms > params["level_wait_time"]
+    nets, ps = jax.vmap(proto.init)(jnp.arange(2, dtype=jnp.int32))
+    if t0_mod:
+        nets, ps = jax.jit(scan_chunk_batched(proto, t0_mod))(nets, ps)
+    plain = jax.jit(scan_chunk_batched(proto, ms))(nets, ps)
+    spec = jax.jit(scan_chunk_batched(proto, ms, t0_mod=t0_mod))(nets, ps)
+    unrolled = jax.jit(jax.vmap(scan_chunk(proto, ms, t0_mod=t0_mod,
+                                           superstep=2)))(nets, ps)
+    _trees_equal(plain, spec)
+    _trees_equal(unrolled, spec)
+    assert int(np.asarray(spec[1].sigs_checked).sum()) > 0
+
+
+def test_phase_plan_lead_and_merged_rest():
+    from wittgenstein_tpu.core.network import phase_plan
+    proto = Handel(**_HANDEL)
+    no = {"verify": False, "periodic": False}
+    plan = phase_plan(proto, 0, 2)
+    assert plan.block == 20 and plan.repeats == 9
+    # t = 0, 1: only t = 1 verifies and disseminates periodically
+    assert plan.lead == (no, {"verify": True, "periodic": True})
+    # the odd ms of the rest keep verify (t = 5, 9, ...), not periodic
+    assert plan.rest == (no, {"verify": True, "periodic": False})
+    # residue 10: t = 10, 11 do neither; t = 21 (slot 1 of the 6th
+    # superstep) disseminates, so the merged rest keeps both there
+    plan = phase_plan(proto, 10, 2)
+    assert plan.lead == (no, no)
+    assert plan.rest == (no, {"verify": True, "periodic": True})
+    # K not dividing the lcm: the block is lcm(20, 8) = 40
+    plan = phase_plan(proto, 0, 8)
+    assert (plan.block, plan.repeats, len(plan.lead)) == (40, 4, 8)
+    # t = 21 is slot 5 of the third superstep
+    assert plan.rest[5] == {"verify": True, "periodic": True}
+    assert plan.rest[1] == {"verify": True, "periodic": False}
+    # a key one hint lacks is kept dynamic (True) in the merge
+    proto.phase_hints = lambda tmod: {} if tmod == 3 else \
+        {"verify": False}
+    assert phase_plan(proto, 0, 2).rest == ({"verify": False},
+                                            {"verify": True})
+    assert phase_plan(Handel(**_HANDEL, desynchronized_start=17), 0,
+                      2) is None
+
+
+def test_bin_order_packed_key_matches_two_keys():
+    """The bin's one packed (rel, dest) key sorts exactly as the two
+    keys it stands for: same stable order, invalid entries last in
+    their own order, same group starts."""
+    from wittgenstein_tpu.core.batched import _bin_order
+    rng = np.random.default_rng(7)
+    n = 13
+    rel = jnp.asarray(rng.integers(1, 9, (3, 200)), jnp.int32)
+    dest = jnp.asarray(rng.integers(0, n, (3, 200)), jnp.int32)
+    valid = jnp.asarray(rng.random((3, 200)) < 0.8)
+    two = _bin_order(rel, dest, valid)
+    one = _bin_order(rel, dest, valid, pack_n=n)
+    _trees_equal(two, one)
+    ref = np.stack([np.lexsort((np.where(v, d, 2**31 - 1),
+                                np.where(v, r, 2**31 - 1)))
+                    for r, d, v in zip(np.asarray(rel), np.asarray(dest),
+                                       np.asarray(valid))])
+    np.testing.assert_array_equal(np.asarray(one[0]), ref)
 
 
 def test_batched_box_split():

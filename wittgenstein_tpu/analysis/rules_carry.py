@@ -17,11 +17,16 @@ Metrics (budgeted per protocol, ratchet-down):
                     leaves, hence its own budget);
   copy_bytes      — total bytes of all >= 1 KB copies in scan bodies
                     (sub-KB copies are CPU scalar-loop noise);
-  dus_bytes       — total dynamic-update-slice bytes in scan bodies.
+  dus_bytes       — total dynamic-update-slice bytes in scan bodies;
+  body_plane_copies, body_boxcount_copies
+                  — the same counts in the one scan body that copies
+                    most: what one superstep pays, where a program has
+                    more than one body (the phase-specialized batched
+                    scan's lead block and its inner rest scan).
 
 Counts are summed across every scan-shaped while body in the module
-(the phase-specialized build has one; nested CPU scatter loops are
-excluded by the carry-width cut — analysis/hlo.scan_bodies).
+(nested CPU scatter loops are excluded by the carry-width cut —
+analysis/hlo.scan_bodies).
 """
 
 from __future__ import annotations
@@ -79,16 +84,23 @@ def metrics_from_rows(rows) -> dict:
     (also behind the ordering barrier; its [R, H, N] shape can collide
     with protocol leaves like Handel's emission block, so it gets its
     own budget instead of diluting the strict plane gate)."""
-    plane_copies = sum(r.count for r in rows
-                      if r.op == "copy" and _is_plane(r.leaf))
-    boxcount_copies = sum(r.count for r in rows
-                          if r.op == "copy" and "box_count" in r.leaf)
+    plane = collections.Counter()
+    boxcount = collections.Counter()
+    for r in rows:
+        if r.op == "copy" and _is_plane(r.leaf):
+            plane[r.body] += r.count
+        if r.op == "copy" and "box_count" in r.leaf:
+            boxcount[r.body] += r.count
+    plane_copies = sum(plane.values())
+    boxcount_copies = sum(boxcount.values())
     copy_bytes = sum(r.bytes for r in rows
                      if r.op == "copy" and r.bytes // r.count >= _NOISE_BYTES)
     dus_bytes = sum(r.bytes for r in rows if r.op == "dynamic-update-slice")
     return {"plane_copies": plane_copies,
             "boxcount_copies": boxcount_copies,
-            "copy_bytes": copy_bytes, "dus_bytes": dus_bytes}
+            "copy_bytes": copy_bytes, "dus_bytes": dus_bytes,
+            "body_plane_copies": max(plane.values(), default=0),
+            "body_boxcount_copies": max(boxcount.values(), default=0)}
 
 
 def measure(target) -> dict:
@@ -101,7 +113,8 @@ class CarryCopyRule(Rule):
     name = "carry_copy"
     scope = "protocol"
     budgeted_metrics = ("plane_copies", "boxcount_copies", "copy_bytes",
-                        "dus_bytes")
+                        "dus_bytes", "body_plane_copies",
+                        "body_boxcount_copies")
 
     def run(self, target, budget):
         if not hlo.scan_bodies(target.hlo_text):

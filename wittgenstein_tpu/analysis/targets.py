@@ -194,6 +194,34 @@ def handel_audit_target(n=256, seeds=2, chunk=40,
     return t
 
 
+#: The request plane's phase-specialized batched Handel program (the
+#: compact `core/network.phase_plan` form `CompileRegistry` builds when
+#: the chunk is a multiple of the schedule lcm), audited under its own
+#: name: its lead superstep and its inner rest scan are separate while
+#: bodies, each gated like the plain program's one (carry_copy's
+#: per-body metrics).  Two lcm blocks.
+PHASE_TARGET = "Handel+phase"
+_PHASE_CHUNK = 40
+
+
+def _phase_target(name: str, seeds=SEEDS) -> AnalysisTarget:
+    def build():
+        import jax
+        import jax.numpy as jnp
+
+        from ..core.batched import scan_chunk_batched
+
+        proto = _handel()
+        base = scan_chunk_batched(proto, _PHASE_CHUNK, t0_mod=0)
+        args = jax.vmap(proto.init)(jnp.arange(seeds, dtype=jnp.int32))
+        return base, args, proto, "batched+phase"
+
+    t = AnalysisTarget(name, None)
+    t._build_fn = build
+    t.ms_per_iter = 4       # one superstep in each of the two bodies
+    return t
+
+
 def _registry() -> dict:
     """name -> zero-arg protocol factory at the pinned analysis config.
     Every entry must init + compile on CPU in seconds at these shapes."""
@@ -785,11 +813,14 @@ def target_names() -> tuple:
                  sorted(f"{n}{MEMO_SUFFIX}" for n in MEMO_PROTOCOLS) +
                  sorted(f"{n}{JOURNAL_SUFFIX}"
                         for n in JOURNAL_PROTOCOLS) +
-                 sorted(SS_PROTOCOLS) + sorted(ROUTE_PROTOCOLS))
+                 sorted(SS_PROTOCOLS) + sorted(ROUTE_PROTOCOLS) +
+                 [PHASE_TARGET])
 
 
 def get_target(name: str) -> AnalysisTarget:
     reg = _registry()
+    if name == PHASE_TARGET:
+        return _phase_target(name)
     if name in SS_PROTOCOLS:
         return _ss_target(name)
     if name in ROUTE_PROTOCOLS:

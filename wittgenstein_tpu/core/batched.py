@@ -37,9 +37,32 @@ import jax.numpy as jnp
 
 from .network import (STAGE_BIN, STAGE_INBOX, STAGE_PROTOCOL, STAGE_ROUTE,
                       _route_unicast, check_chunk_config, fast_forward_ok,
-                      superstep_ok)
+                      phase_plan, superstep_ok)
 from .protocol import FAR_FUTURE
 from .state import EngineConfig, Inbox, NetState
+
+
+def _bin_order(rel, dest, valid, pack_n=None):
+    """Per row of [R, m]: the stable (rel, dest)-lexicographic order,
+    invalid entries last (the order of network's two stable argsorts,
+    by dest then by rel, in one sort), and which sorted entries start a
+    (rel, dest) group.  With ``pack_n`` (dest < pack_n) it sorts one
+    int32 key ``rel * pack_n + dest``, which the caller may ask for only
+    where that cannot overflow: a sort of two operands compiles in well
+    under half the time of one of three."""
+    big = jnp.int32(0x7FFFFFFF)
+    if pack_n:
+        keys = (jnp.where(valid, rel * pack_n + dest, big),)
+    else:
+        keys = (jnp.where(valid, rel, big), jnp.where(valid, dest, big))
+    pos = jnp.broadcast_to(jnp.arange(rel.shape[1], dtype=jnp.int32),
+                           rel.shape)
+    *keys, order = jax.lax.sort((*keys, pos), dimension=1, is_stable=True,
+                                num_keys=len(keys))
+    new_grp = keys[0] != jnp.roll(keys[0], 1, axis=1)
+    for key in keys[1:]:
+        new_grp = new_grp | (key != jnp.roll(key, 1, axis=1))
+    return order, new_grp.at[:, 0].set(True)
 
 
 def _batched_bin(cfg: EngineConfig, net: NetState, t, src, dest, arrival,
@@ -68,19 +91,11 @@ def _batched_bin(cfg: EngineConfig, net: NetState, t, src, dest, arrival,
     p, ns = cfg.box_split, cfg.split_n
     r, m = src.shape
     rel = arrival - t
-    big = jnp.int32(0x7FFFFFFF)
-    rel_k = jnp.where(valid, rel, big)
-    dest_k = jnp.where(valid, dest, big)
-    o1 = jnp.argsort(dest_k, axis=1, stable=True)
-    order = jnp.take_along_axis(
-        o1, jnp.argsort(jnp.take_along_axis(rel_k, o1, axis=1), axis=1,
-                        stable=True), axis=1)
-    rel_s = jnp.take_along_axis(rel_k, order, axis=1)
-    dest_s = jnp.take_along_axis(dest_k, order, axis=1)
     idx = jnp.arange(m, dtype=jnp.int32)[None, :]
-    new_grp = ((rel_s != jnp.roll(rel_s, 1, axis=1)) |
-               (dest_s != jnp.roll(dest_s, 1, axis=1)))
-    new_grp = new_grp.at[:, 0].set(True)
+    # rel < 2 * horizon by the arrival contract, 0 <= dest < n
+    order, new_grp = _bin_order(
+        rel, dest, valid,
+        pack_n=n if 2 * cfg.horizon * n < 2 ** 31 - 1 else None)
     rank = idx - jax.lax.cummax(jnp.where(new_grp, idx, 0), axis=1)
 
     h_s = jnp.take_along_axis(arrival % cfg.horizon, order, axis=1)
@@ -94,36 +109,46 @@ def _batched_bin(cfg: EngineConfig, net: NetState, t, src, dest, arrival,
     ok_s = ok_s & (slot < c)
 
     sub_total = cfg.horizon * ns * c
-    payload_s = jnp.take_along_axis(payload, order[:, :, None], axis=1)
-    src_s = jnp.take_along_axis(src, order, axis=1)
-    size_s = jnp.take_along_axis(size, order, axis=1)
+    cell_w = jnp.where(ok_s, cell, r * cfg.horizon * n)
+    # Per-seed cell index + seed-stride fold: one scatter per plane, no
+    # per-seed serialization.
+    flat = [jnp.where(ok_s & (d_s - j * ns >= 0) & (d_s - j * ns < ns),
+                      (h_s * ns + d_s - j * ns) * c + slot + rix * sub_total,
+                      r * sub_total) for j in range(p)]
+    # where each entry's values sit in the flattened [R * m] inputs
+    take = (order + rix * m).reshape(-1)
+    if p == 1:
+        # Sort by the final ring index (cell * C + slot, the dropped
+        # last), carrying only that position: scatters whose indices are
+        # sorted compile without the index sort each would otherwise
+        # make of its own, and a sort that carried every value would
+        # compile several times longer than these gathers.
+        flat[0], take = jax.lax.sort((flat[0].reshape(-1), take),
+                                     num_keys=1)
+        cell_w = flat[0] // c
+    words = [payload[:, :, fi].reshape(-1)[take]
+             for fi in range(cfg.payload_words)]
+    src_s = src.reshape(-1)[take]
+    size_s = size.reshape(-1)[take]
+
+    def put(plane, at, val):
+        return plane.reshape(-1).at[at.reshape(-1)].set(
+            val.reshape(-1), mode="drop", unique_indices=True,
+            indices_are_sorted=p == 1).reshape(plane.shape)
+
     box_data = list(net.box_data)
     box_src = list(net.box_src)
     box_size = list(net.box_size)
     for j in range(p):
-        dj = d_s - j * ns
-        in_j = ok_s & (dj >= 0) & (dj < ns)
-        # Per-seed cell index + seed-stride fold: one scatter, no
-        # per-seed serialization.
-        flat_j = (h_s * ns + dj) * c + jnp.where(in_j, slot, 0) + \
-            rix * sub_total
-        flat_jw = jnp.where(in_j, flat_j, r * sub_total).reshape(-1)
         for fi in range(cfg.payload_words):
-            pl = box_data[fi * p + j]
-            box_data[fi * p + j] = pl.reshape(-1).at[flat_jw].set(
-                payload_s[:, :, fi].reshape(-1), mode="drop",
-                unique_indices=True).reshape(pl.shape)
-        box_src[j] = box_src[j].reshape(-1).at[flat_jw].set(
-            src_s.reshape(-1), mode="drop",
-            unique_indices=True).reshape(box_src[j].shape)
-        box_size[j] = box_size[j].reshape(-1).at[flat_jw].set(
-            size_s.reshape(-1), mode="drop",
-            unique_indices=True).reshape(box_size[j].shape)
-    cell_w = jnp.where(ok_s, cell, r * cfg.horizon * n).reshape(-1)
+            box_data[fi * p + j] = put(box_data[fi * p + j], flat[j],
+                                       words[fi])
+        box_src[j] = put(box_src[j], flat[j], src_s)
+        box_size[j] = put(box_size[j], flat[j], size_s)
+    cell_w = cell_w.reshape(-1)
     box_count = cnt_flat.at[cell_w].add(
-        jnp.ones_like(cell_w, dtype=jnp.int32) *
-        ok_s.reshape(-1).astype(jnp.int32),
-        mode="drop").reshape(net.box_count.shape)
+        jnp.ones_like(cell_w), mode="drop",
+        indices_are_sorted=p == 1).reshape(net.box_count.shape)
     n_dropped = jnp.sum(jnp.take_along_axis(valid, order, axis=1) & ~ok_s,
                         axis=1).astype(jnp.int32)
     return net.replace(box_data=tuple(box_data), box_src=tuple(box_src),
@@ -385,8 +410,17 @@ def scan_chunk_batched(protocol, ms: int, t0_mod=None, plane_barrier=True,
                        fast_forward=False, superstep: int = 2):
     """Batched twin of scan_chunk(superstep=K) for vmap-batched state
     (leaves [R, ...]); K defaults to the universally-valid 2.  Same
-    phase-specialization contract; chunk must be K-aligned and a
-    multiple of the (K-adjusted) schedule lcm when t0_mod is given.
+    phase-specialization contract (enter at ``time % schedule_lcm ==
+    t0_mod``); chunk must be K-aligned and a multiple of the (K-adjusted)
+    schedule lcm when t0_mod is given.  The specialized scan runs the
+    compact `network.phase_plan` form — per schedule block one lead
+    superstep, then one inner scan of the OR-merged rest — rather than
+    `scan_chunk`'s unrolled block: it traces two superstep bodies, not
+    lcm/K (its compiled code grows by one body, its temporaries not at
+    all), while the rest
+    still skips what the declared schedule proves cannot fire (Handel
+    at K=2, lcm 20: verification on the even ms of 9 of 10 supersteps,
+    periodic dissemination and its full-width outbox on the same 9).
     `plane_barrier` — see `step_kms_batched`.  `fast_forward=True` swaps
     the dense scan for the quiet-window while loop
     (`fast_forward_chunk_batched`, stats dropped); incompatible with
@@ -406,37 +440,36 @@ def scan_chunk_batched(protocol, ms: int, t0_mod=None, plane_barrier=True,
         return run_ff
     check_chunk_config(protocol, ms, t0_mod=t0_mod, superstep=k)
     _check_batched_scope(protocol, ms, k)
-    lcm = getattr(protocol, "schedule_lcm", None) if t0_mod is not None \
-        else None
-    if lcm and lcm % k:
-        import math
-        lcm = lcm * k // math.gcd(lcm, k)
-    if lcm:
-        if ms % lcm:
-            raise ValueError(f"chunk {ms} not a multiple of lcm {lcm}")
-        sched = getattr(protocol, "schedule_lcm")
-        hints = [protocol.phase_hints((t0_mod + dt) % sched)
-                 for dt in range(lcm)]
-        blocks = ms // lcm
+
+    def step(carry, hints_k=None):
+        return step_kms_batched(protocol, *carry, k, hints_k=hints_k,
+                                plane_barrier=plane_barrier)
+
+    plan = None if t0_mod is None else phase_plan(protocol, t0_mod, k)
+    if plan is not None:
+        if ms % plan.block:
+            raise ValueError(f"chunk {ms} not a multiple of lcm "
+                             f"{plan.block}")
 
         def run_spec(net, pstate):
-            def body(carry, _):
-                net, ps = carry
-                for i in range(0, len(hints), k):
-                    net, ps = step_kms_batched(
-                        protocol, net, ps, k, hints_k=hints[i:i + k],
-                        plane_barrier=plane_barrier)
-                return (net, ps), ()
-            (net, pstate), _ = jax.lax.scan(body, (net, pstate),
-                                            length=blocks)
+            def rest(carry, _):
+                return step(carry, plan.rest), ()
+
+            def block(carry, _):
+                carry = step(carry, plan.lead)
+                if plan.repeats:
+                    carry, _ = jax.lax.scan(rest, carry,
+                                            length=plan.repeats)
+                return carry, ()
+            (net, pstate), _ = jax.lax.scan(block, (net, pstate),
+                                            length=ms // plan.block)
             return net, pstate
 
         return run_spec
 
     def run(net, pstate):
         def body(carry, _):
-            return step_kms_batched(protocol, *carry, k,
-                                    plane_barrier=plane_barrier), ()
+            return step(carry), ()
         (net2, p2), _ = jax.lax.scan(body, (net, pstate), length=ms // k)
         return net2, p2
 

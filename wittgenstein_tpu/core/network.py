@@ -32,6 +32,8 @@ Design notes vs the reference:
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
@@ -876,6 +878,59 @@ def check_chunk_config(protocol, ms, t0_mod=None, superstep=1,
                     "this one.)")
 
 
+class PhasePlan(NamedTuple):
+    """One schedule block of a phase-specialized K-ms scan (see
+    `phase_plan`)."""
+    block: int      # ms per block: the K-adjusted schedule lcm
+    steps: tuple    # the block's block // K supersteps: K hints each
+
+    @property
+    def lead(self) -> tuple:
+        """The block's first superstep: its K hints as they are."""
+        return self.steps[0]
+
+    @property
+    def rest(self) -> tuple:
+        """K hints OR-merged over the block's other supersteps."""
+        return tuple(_or_hints(slot) for slot in zip(*self.steps[1:]))
+
+    @property
+    def repeats(self) -> int:
+        """Supersteps after the lead: block // K - 1."""
+        return len(self.steps) - 1
+
+
+def _or_hints(hints) -> dict:
+    """Per-key OR of hint dicts; a key a dict lacks counts as True (the
+    protocol keeps that gate dynamic), so the merge is sound for every
+    ms it stands for."""
+    keys = set().union(*hints)
+    return {key: any(h.get(key, True) for h in hints) for key in keys}
+
+
+def phase_plan(protocol, t0_mod: int, superstep: int):
+    """The static schedule of a phase-specialized K-ms scan entered at
+    ``time % schedule_lcm == t0_mod``: blocks of `block` ms (the lcm
+    grown to a multiple of K), each `steps` of K per-ms hints.
+    `scan_chunk` unrolls the steps; `scan_chunk_batched` runs the
+    compact form, the `lead` superstep once, then `repeats` supersteps
+    of one `rest` body, whose hints are the per-slot OR of the block's
+    other supersteps.  A hint of True keeps the step's dynamic gate, so
+    the merged body is exact on every superstep it stands for, and the
+    block traces two superstep bodies instead of block // K.  None when
+    the protocol declares no static schedule."""
+    lcm = getattr(protocol, "schedule_lcm", None)
+    if not lcm:
+        return None
+    import math
+    k = superstep
+    block = lcm * k // math.gcd(lcm, k)
+    hints = [protocol.phase_hints((t0_mod + dt) % lcm)
+             for dt in range(block)]
+    return PhasePlan(block, tuple(tuple(hints[i:i + k])
+                                  for i in range(0, block, k)))
+
+
 #: Default upper bound for auto-picked superstep windows: past ~32 the
 #: amortized fixed cost is already < 1/32 of its per-ms value while the
 #: unrolled window body keeps growing compile time linearly.
@@ -1099,13 +1154,10 @@ def scan_chunk(protocol, ms: int, t0_mod=None, allow_unaligned=False,
             return net, pstate
 
         return run_ff
-    lcm = getattr(protocol, "schedule_lcm", None) if t0_mod is not None \
-        else None
-    if lcm and superstep > 1 and lcm % superstep:
-        # Group hints across a K-aligned super-period.
-        import math
-        lcm = lcm * superstep // math.gcd(lcm, superstep)
-    if lcm:
+    plan = None if t0_mod is None else phase_plan(protocol, t0_mod,
+                                                  superstep)
+    if plan is not None:
+        lcm = plan.block
         if ms % lcm and not allow_unaligned:
             raise ValueError(
                 f"phase-specialized chunk length {ms} is not a multiple of "
@@ -1114,19 +1166,16 @@ def scan_chunk(protocol, ms: int, t0_mod=None, allow_unaligned=False,
                 "first call. Use an lcm-multiple chunk, or pass "
                 "allow_unaligned=True for a one-shot chunk and track "
                 "t0_mod yourself.")
-        sched = getattr(protocol, "schedule_lcm")
-        hints = [protocol.phase_hints((t0_mod + dt) % sched)
-                 for dt in range(lcm)]
+        hints = [h for step in plan.steps for h in step]
         blocks, tail = divmod(ms, lcm)
 
         def run_spec(net, pstate):
             def body(carry, _):
                 net, ps = carry
                 if superstep > 1:
-                    for i in range(0, len(hints), superstep):
-                        net, ps = step_kms(
-                            protocol, net, ps, superstep,
-                            hints_k=hints[i:i + superstep])
+                    for hints_k in plan.steps:
+                        net, ps = step_kms(protocol, net, ps, superstep,
+                                           hints_k=hints_k)
                 else:
                     for h in hints:
                         net, ps = step_ms(protocol, net, ps, hints=h)

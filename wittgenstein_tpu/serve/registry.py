@@ -148,6 +148,8 @@ class CompileRegistry:
         self._programs: dict = {}
         self.hits = 0
         self.misses = 0
+        #: programs built phase-specialized (`_phase_entry`)
+        self.phase_specialized = 0
         self.compiles = compile_counters()
         self._compiles0 = self.compiles.totals()
 
@@ -199,7 +201,9 @@ class CompileRegistry:
             from ..core.network import fast_forward_chunk, scan_chunk
             if eng == "batched":
                 from ..core.batched import scan_chunk_batched
-                base = scan_chunk_batched(proto, ms, superstep=k)
+                base = scan_chunk_batched(
+                    proto, ms, superstep=k,
+                    t0_mod=self._phase_entry(proto, ms, k))
             elif eng == "fast_forward":
                 base = fast_forward_chunk(proto, ms, seed_axis=True,
                                           superstep=k)
@@ -273,6 +277,20 @@ class CompileRegistry:
         return CatalogProgram(jax.jit(base), spec.route_kernel, cat,
                               spec.compile_key(), plane)
 
+    def _phase_entry(self, proto, ms: int, k: int):
+        """0 when the batched program can be phase-specialized, else
+        None.  The request plane enters a chunk program only at
+        multiples of `chunk_ms` (fresh lanes at 0; checkpoints,
+        preemptions and forks at chunk boundaries —
+        tests/test_phase_plan.py), so a chunk that is a multiple of the
+        protocol's declared schedule block always enters at phase 0."""
+        from ..core.network import phase_plan
+        plan = phase_plan(proto, 0, k)
+        if plan is None or ms % plan.block:
+            return None
+        self.phase_specialized += 1
+        return 0
+
     # ------------------------------------------------------------- export
 
     def stats(self) -> dict:
@@ -291,7 +309,8 @@ class CompileRegistry:
         (schema: BENCH_NOTES.md r11) — the warm/cold story of every
         submit, in the same one-flat-dict convention as
         `engine_metrics_block`."""
-        out = {**self.stats(), **self.compile_stats()}
+        out = {**self.stats(), "phase_specialized": self.phase_specialized,
+               **self.compile_stats()}
         if extra:
             out.update(extra)
         return out
