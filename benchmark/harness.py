@@ -148,7 +148,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     tiny cell added as files), `patch` (wraps the timed chunk program:
     a fault underneath) or `attack` (the control: a
     `ScenarioSpec.attack` perturbation planted in the program).
-    `info`, if given, receives the set-up and check seconds."""
+    `info`, if given, receives the set-up and check seconds.  The kind
+    finds the protocol's reference under `repo`'s ``benchmark/`` (the
+    ``root`` of its context)."""
     import jax
     bench = load_benchmark(repo)
     root = repo / "benchmark"
@@ -159,7 +161,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     ctx = {"cell": c, "config": config(bench, c["config"], repo),
            "traffic": mix, "seed": int(seed), "seconds": float(seconds),
            "trace": bool(trace), "devices": devices, "t0": t0,
-           "pre_start": pre_start, "out_dir": root / ".out" / c["name"],
+           "pre_start": pre_start, "root": root,
+           "out_dir": root / ".out" / c["name"],
            "patch": patch, "attack": attack}
     record = kind(mix["kind"]).run(ctx)
     metrics = {}

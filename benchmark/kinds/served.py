@@ -8,6 +8,8 @@ the requests in flight finish.  Parameters of a mix:
   seeds_per_request  seeds of each request (distinct across requests)
   sim_ms, chunk_ms   each request's span and the chunk it is driven in
   obs                the observation planes each request asks for
+  trace_requests     requests each client submits in a ``--trace 1``
+                     run (its traced window)
   check              {"requests": sampled finished requests}
 
 Progress is counted at chunk boundaries from `Request.chunk_totals`:
@@ -17,11 +19,15 @@ time to the last settle.  The request plane's spans
 the sampled requests, the plain reference re-runs every seed over the
 request's span and the summary sums (messages sent and received, live
 nodes done) must equal the request's; a request that fails counts as
-wrong.
+wrong.  The reference is the protocol's (`check.reference`), found
+before anything is built.  A ``--trace 1`` run traces its window
+(`bench.window`) and reduces the trace as the stream kind does: busy
+time and idle gaps, no stage table.
 """
 
 from __future__ import annotations
 
+import shutil
 import threading
 import time
 
@@ -29,7 +35,7 @@ import numpy as np
 
 from .. import check
 from ..harness import device_block
-from .stream import WARM_SEED0, batch_seeds, reference_params
+from .stream import WARM_SEED0, batch_seeds, reduce_trace, reference_params
 
 POLL_S = 0.01
 #: the request artifact's summary values compared with the reference
@@ -58,7 +64,29 @@ def _serve_one(svc, body, timeout_s):
     raise TimeoutError(f"request {rid} did not settle in {timeout_s} s")
 
 
+def _warm_up(svc, ctx, mix, warm) -> list:
+    """Set-up: requests queued under one compile key run as one group,
+    one program over all their seeds, so the clients can form a group of
+    1 to `clients` requests.  Each such group runs once here, on
+    throwaway seeds and drained in this thread (the service's own drain
+    thread starts with the window's first submit), so that every
+    program and shape the window can use is compiled.  Returns the
+    errors of requests that did not settle."""
+    from wittgenstein_tpu.serve.spec import ScenarioSpec
+    sched, errors = svc.scheduler, []
+    for k in range(mix["clients"], 0, -1):
+        rids = [sched.submit(ScenarioSpec.from_json(_body(
+            ctx, mix, warm + j * len(warm), ctx.get("attack"))))
+            for j in range(k)]
+        sched.run_pending()
+        errors += [f"warm-up {rid}: {sched.request(rid).status}"
+                   for rid in rids if sched.request(rid).status != "done"]
+    return errors
+
+
 def run(ctx: dict) -> dict:
+    import jax
+
     import wittgenstein_tpu.models  # noqa: F401  (registers protocols)
     from wittgenstein_tpu.serve.instrument import (SPAN_LAUNCH,
                                                    SPAN_SETTLE,
@@ -68,26 +96,44 @@ def run(ctx: dict) -> dict:
 
     mix = ctx["traffic"]
     width, seed = mix["seeds_per_request"], ctx["seed"]
+    ref = check.reference(ctx["root"], ctx["config"]["spec"]["protocol"])
+    traced = ctx["trace"]
     out_dir = ctx["out_dir"]
+    tdir = out_dir / "trace"
     out_dir.mkdir(parents=True, exist_ok=True)
+    (out_dir / "ledger.jsonl").unlink(missing_ok=True)
     ins = Instrumentation()
     svc = Service(Scheduler(ledger_path=str(out_dir / "ledger.jsonl"),
                             instrument=ins), auto=True)
+    if ctx["patch"] is not None:       # a fault under every program
+        reg = svc.scheduler.registry
+        build = reg.chunk_fn
+        reg.chunk_fn = lambda *a, **k: ctx["patch"](build(*a, **k))
+    served, mu, errors = [], threading.Lock(), []
     try:
-        # set-up: one request on throwaway seeds compiles every plane
         t = time.monotonic()
         warm = np.arange(width, dtype=np.int32) + WARM_SEED0
-        _serve_one(svc, _body(ctx, mix, warm, ctx.get("attack")), 3600)
+        errors += _warm_up(svc, ctx, mix, warm)
         load_s = time.monotonic() - t
+        if traced:
+            shutil.rmtree(tdir, ignore_errors=True)
+            jax.profiler.start_trace(str(tdir))
+        compiles = svc.scheduler.registry.compile_stats()
         t_w0 = time.monotonic()
         s_w0 = ins.now()
         setup_s = ctx["pre_start"] + (t_w0 - ctx["t0"])
-        served, mu, errors = [], threading.Lock(), []
         counter = iter(range(1 << 30))
 
+        def more(n):
+            if traced:
+                return n < mix["trace_requests"]
+            return time.monotonic() - t_w0 < ctx["seconds"]
+
         def client():
+            n = 0
             try:
-                while time.monotonic() - t_w0 < ctx["seconds"]:
+                while more(n):
+                    n += 1
                     with mu:
                         k = next(counter)
                     seeds = batch_seeds(seed, k, width)
@@ -103,29 +149,37 @@ def run(ctx: dict) -> dict:
 
         threads = [threading.Thread(target=client, daemon=True)
                    for _ in range(mix["clients"])]
-        for th in threads:
-            th.start()
-        for th in threads:
-            th.join()
+        with jax.profiler.TraceAnnotation("bench.window"):
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join()
+        if traced:
+            jax.profiler.stop_trace()
         wall = max((s[0] for s in served), default=0.0)
         boundaries = sum(len(svc.scheduler.request(rid).chunk_totals)
                          for _, rid, _, _ in served)
         spans = [r for r in ins.spans.snapshot() if r["t0"] >= s_w0]
+        window_compile_s = (svc.scheduler.registry.compile_stats()
+                            ["compile_s"] - compiles["compile_s"])
         device = device_block(ctx["devices"])
     finally:
         svc.close()
 
     record = {
-        "setup": {"setup_s": setup_s, "load_s": load_s},
+        "setup": {"setup_s": setup_s, "load_s": load_s,
+                  "compile": compiles},
         "window": {"seconds": wall, "requests": len(served),
                    "seed_sim_ms": boundaries * mix["chunk_ms"] * width,
                    "lockstep_ms": boundaries * mix["chunk_ms"]},
-        "serve": {"window_s": wall,
+        "serve": {"window_s": wall, "compile_s": window_compile_s,
                   "launch_s": sum(r["dur"] for r in spans
                                   if r["name"] == SPAN_LAUNCH),
                   "settle_s": sum(r["dur"] for r in spans
                                   if r["name"] == SPAN_SETTLE)},
         "device": device, "attempted": len(served) + len(errors)}
+    if traced:
+        record.update(reduce_trace(tdir, record))
     t = time.monotonic()
     rng = np.random.default_rng([seed % 2 ** 64, 1 << 21])
     pick = rng.choice(len(served), size=min(mix["check"]["requests"],
@@ -139,15 +193,15 @@ def run(ctx: dict) -> dict:
         _, _, seeds, res = served[int(i)]
         got = res["summary"]
         want = dict.fromkeys(SUMMARY, 0)
-        for ref in check.reference_trajectories(params, seeds,
+        for end in check.reference_trajectories(ref, params, seeds,
                                                 mix["chunk_ms"], n_chunks):
-            ref = ref[-1]
-            live = ~np.asarray(ref["down"])
-            want["msg_sent"] += int(np.asarray(ref["msg_sent"]).sum())
+            end = end[-1]
+            live = ~np.asarray(end["down"])
+            want["msg_sent"] += int(np.asarray(end["msg_sent"]).sum())
             want["msg_received"] += int(
-                np.asarray(ref["msg_received"]).sum())
+                np.asarray(end["msg_received"]).sum())
             want["done_count"] += int(
-                ((np.asarray(ref["done_at"]) > 0) & live).sum())
+                ((np.asarray(end["done_at"]) > 0) & live).sum())
         n = sum(got[k] != v for k, v in want.items())
         wrong += n
         bad += n > 0

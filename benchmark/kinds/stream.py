@@ -13,11 +13,21 @@ a mix (`traffic/<mix>.json`):
                 `--seed`), "chunks": boundaries the reference follows
                 from the start}
 
-Set-up: build the protocol, jit the seed init (`jax.vmap(proto.init)`,
-one program), warm init, chunk and the check's reduction with one chunk
-on a throwaway batch.  Window, from t = 0: init the batch of seeds drawn
-from `--seed`, launch chunks back to back with one chunk queued ahead,
-read a scalar of each as it completes, stop launching at `--seconds`.
+Set-up: find the protocol's reference (`check.reference`: refused here,
+before anything is built, where no module or two claim it), build the
+protocol, jit the seed init (`jax.vmap(proto.init)`, one program), warm
+init, chunk and the check's reduction with one chunk on a throwaway
+batch.  Window, from t = 0: init the batch of seeds drawn from
+`--seed`, launch chunks back to back with one chunk queued ahead, read
+a scalar of each as it completes, stop launching at `--seconds`.
+
+A ``--trace 1`` run traces `trace_chunks` chunks, reads the device's
+memory peak, then reduces the trace to busy time and idle gaps
+(`trace.py`) and to device time per engine stage (`stages.py`, with the
+chunk program's op names from a compile that loads the same executable
+from the cache: after the peak is read, so it stays out of it), and
+deletes the trace.  The set-up's trace and compile seconds are the
+registry's `compile_stats` at the window's start.
 """
 
 from __future__ import annotations
@@ -27,7 +37,7 @@ import time
 
 import numpy as np
 
-from .. import check, trace as trace_mod
+from .. import check, stages, trace as trace_mod
 from ..harness import device_block
 
 #: seeds of the throwaway warm-up batch (never a window's seeds: those
@@ -73,6 +83,7 @@ def run(ctx: dict) -> dict:
     mix = ctx["traffic"]
     width, chunk_ms = mix["seeds"], mix["chunk_ms"]
     spec = spec_of(ctx["config"], mix, ctx.get("attack"))
+    ref = check.reference(ctx["root"], spec.protocol)
     rng = np.random.default_rng([ctx["seed"] % 2 ** 64, 1 << 20])
     sample = check.sample_seeds(rng, width, mix["check"]["seeds"])
     sample_dev = jnp.asarray(sample, jnp.int32)
@@ -82,13 +93,14 @@ def run(ctx: dict) -> dict:
     # traced, so one span covers the build, the compile (or the compile
     # cache's load) and one warm chunk.
     t = time.monotonic()
+    registry = CompileRegistry()
     with ann("bench.build"):
         proto = spec.build_protocol()
-    chunk = CompileRegistry().chunk_fn(spec, None, proto=proto)
+    program = chunk = registry.chunk_fn(spec, None, proto=proto)
     if ctx["patch"] is not None:
         chunk = ctx["patch"](chunk)
     init = jax.jit(lambda s: jax.vmap(proto.init)(s))
-    summarize = jax.jit(check.summarize)
+    summarize = jax.jit(check.summarizer(ref))
     with ann("bench.load"):
         warm = init(jnp.arange(width, dtype=jnp.int32) + WARM_SEED0)
         warm = chunk(*warm)
@@ -102,6 +114,7 @@ def run(ctx: dict) -> dict:
     if traced:
         shutil.rmtree(tdir, ignore_errors=True)
         jax.profiler.start_trace(str(tdir))
+    compiles = registry.compile_stats()
     t_w0 = time.monotonic()
     setup_s = ctx["pre_start"] + (t_w0 - ctx["t0"])
     with ann("bench.window"):
@@ -114,24 +127,44 @@ def run(ctx: dict) -> dict:
     # ---------------------------------------------------------- record
     wall = done[-1]["t"]
     record = {
-        "setup": {"setup_s": setup_s, "load_s": load_s},
+        "setup": {"setup_s": setup_s, "load_s": load_s,
+                  "compile": compiles},
         "window": {"seconds": wall, "chunks": len(done),
                    "seed_sim_ms": width * chunk_ms * len(done),
                    "lockstep_ms": chunk_ms * len(done)},
         "device": device, "attempted": width * len(done)}
     if traced:
-        red = trace_mod.reduce_dir(tdir)
-        shutil.rmtree(tdir, ignore_errors=True)
-        record["trace"] = red
-        if red is not None:          # None: no accelerator plane (CPU)
-            record["device"]["busy_s"] = red["busy_s"]
-            record["device"]["window_s"] = red["window_s"]
-            record["breakdown"] = {"device_ops": red["device_ops"],
-                                   "idle_gaps": red["idle_gaps"]}
+        shapes = jax.eval_shape(init, jax.ShapeDtypeStruct((width,),
+                                                           jnp.int32))
+        record.update(reduce_trace(tdir, record, program, shapes))
     t = time.monotonic()
-    record.update(_compare(spec, mix, seeds[0], sample, done))
+    record.update(_compare(ref, spec, mix, seeds[0], sample, done))
     record["check_s"] = time.monotonic() - t
     return record
+
+
+def reduce_trace(tdir, record, program=None, shapes=None) -> dict:
+    """A traced window's busy time, idle gaps and (given the chunk
+    `program` and its state `shapes`) device time per stage, for the
+    record; the trace is deleted.  Sets ``device.busy_s`` and
+    ``device.window_s``.  Without an accelerator plane (the CPU) there
+    is nothing to read: the trace reads None and no stage is given."""
+    red = trace_mod.reduce_dir(tdir)
+    out = {"trace": red}
+    if red is not None:
+        record["device"]["busy_s"] = red["busy_s"]
+        record["device"]["window_s"] = red["window_s"]
+        out["breakdown"] = {"device_ops": red["device_ops"],
+                            "idle_gaps": red["idle_gaps"]}
+        if program is not None:
+            sim = record["window"]["seed_sim_ms"]
+            st = stages.reduce_dir(
+                tdir, stages.chunk_op_names(program, shapes), sim)
+            red["stages"] = st["ms_per_simms"]
+            red["sub"] = {k: 1000.0 * v / sim for k, v in st["sub"].items()}
+            out["breakdown"]["device_stages"] = stages.device_stages(st)
+    shutil.rmtree(tdir, ignore_errors=True)
+    return out
 
 
 def _window(ctx, mix, init, chunk, summarize, sample_dev, t_w0):
@@ -178,7 +211,7 @@ def _complete(entry, done, t_w0):
                  "summ": summ})
 
 
-def _compare(spec, mix, seeds0, sample, done) -> dict:
+def _compare(ref, spec, mix, seeds0, sample, done) -> dict:
     """`mismatched_values` (see `check`) beside its limit, and the
     seeds found wrong."""
     first = [d for d in done if d["batch"] == 0]
@@ -194,13 +227,13 @@ def _compare(spec, mix, seeds0, sample, done) -> dict:
         bad.update((d["batch"], int(i)) for i in lost)
     n_ref = min(len(first), mix["check"]["chunks"])
     params = reference_params(spec)
-    fields = check.NODE_FIELDS + check.PROTO_FIELDS + check.LOSS_FIELDS
     refs = check.reference_trajectories(
-        params, [int(seeds0[i]) for i in sample], mix["chunk_ms"], n_ref)
-    for j, (i, ref) in enumerate(zip(sample, refs)):   # value for value
-        prog = [{**{f: d["summ"][f][j] for f in fields},
+        ref, params, [int(seeds0[i]) for i in sample], mix["chunk_ms"],
+        n_ref)
+    for j, (i, want) in enumerate(zip(sample, refs)):  # value for value
+        prog = [{**{f: d["summ"][f][j] for f in check.fields(ref)},
                  "time": d["summ"]["time"][i]} for d in first[:n_ref]]
-        n = check.mismatches(prog, ref)
+        n = check.mismatches(ref, prog, want)
         wrong += n
         if n:
             bad.add((0, int(i)))

@@ -1,0 +1,10 @@
+"""engine.bin_ms_per_simms: device time of the chunk program's
+``engine.bin`` (the outbox binned into the mailbox ring: sorts, ranks,
+scatters; `benchmark/stages.py`) per seed-sim-ms of the traced window,
+in ms. None where the trace has no stage table (no accelerator plane,
+or a program without stage scopes)."""
+
+
+def read(record):
+    stages = (record.get("trace") or {}).get("stages") or {}
+    return stages.get("engine.bin") or None

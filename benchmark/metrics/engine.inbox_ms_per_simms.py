@@ -1,0 +1,10 @@
+"""engine.inbox_ms_per_simms: device time of the chunk program's
+``engine.inbox`` (ring-row reads, delivery masks, receive counters;
+`benchmark/stages.py`) per seed-sim-ms of the traced window, in ms.
+None where the trace has no stage table (no accelerator plane, or a
+program without stage scopes)."""
+
+
+def read(record):
+    stages = (record.get("trace") or {}).get("stages") or {}
+    return stages.get("engine.inbox") or None

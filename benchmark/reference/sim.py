@@ -66,10 +66,23 @@ DEFAULTS = dict(pairing_time=3, level_wait_time=50, extra_cycle=10,
 PLACEMENT = "CITIES_SPEED=CONSTANT_TOR=0.00"
 LATENCY = "NetworkLatencyByDistanceWJitter"
 
+#: the registry class name this module simulates (`benchmark/check.py`
+#: finds it by this name); Handel's cardinal mode is the same class
+PROTOCOL = "Handel"
 NODE_FIELDS = ("done_at", "msg_sent", "msg_received", "bytes_sent",
                "bytes_received")
 PROTO_FIELDS = ("sigs_checked", "msg_filtered")
+PROTO_LOSSES = ("evicted",)
+#: the configurations' parameters as the CPU tests cut them: 64 nodes,
+#: 10% down, the threshold 0.99 of the live
+TINY = dict(node_count=64, nodes_down=6, threshold=57)
 BIG = 1 << 40
+
+
+def tables(evaluate):
+    """What the device evaluates for the reference: the latency tables
+    (`world.latency_tables`)."""
+    return world.latency_tables(evaluate)
 
 
 def _params(params: dict) -> dict:

@@ -26,36 +26,21 @@ program's ops carry no stage scope at all (an executable compiled
 before the scopes existed and loaded from the persistent cache, whose
 key ignores metadata) the stages are None, never "all carry".
 
-    python3 benchmark/stages.py --workload <cell> --seed <n>
-
-runs the cell as ``benchmark/run.py --trace 1`` does and prints its
-result line with the stage table (``breakdown.device_stages``) and,
-under ``stages``, each stage's ms per seed-sim-ms, the set-up's trace
-and compile seconds (the program's `serve.registry.compile_counters`,
-read when the window has ended: nothing compiles inside it) and the
-traced window's seconds per chunk.  That entry, with its swap of
-`trace.reduce_dir` and the recompile in `chunk_op_names`, stands in
-until the stream kind calls `reduce_dir` itself; then only `op_names`,
-`reduce` and `device_stages` are needed.
+The stream kind (`kinds/stream.py`) reduces a ``--trace 1`` run's trace
+here before deleting it: the stages' ms per seed-sim-ms, the sub-scopes
+and ``breakdown.device_stages`` go into its record, where the
+``engine.*_ms_per_simms`` and ``protocol.step_ms_per_simms`` readers
+find them.
 """
 
 from __future__ import annotations
 
-import argparse
 import bisect
 import glob
-import json
 import os
 import re
-import sys
-import time
-from pathlib import Path
 
-if __name__ == "__main__":
-    T0 = time.monotonic()
-    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-from benchmark import trace as trace_mod  # noqa: E402
+from . import trace as trace_mod
 
 #: the engine's stage scopes, in program order, then what no scope holds
 STAGES = ("engine.inbox", "protocol.step", "engine.route", "engine.bin")
@@ -257,90 +242,11 @@ def reduce_dir(trace_dir, names=None, seed_sim_ms=None):
 
 # ------------------------------------------------------------- a run
 
-def chunk_op_names(cfg: dict, mix: dict) -> dict:
-    """`op_names` of the cell's chunk program, compiled as the stream
-    kind compiles it (in a process that ran the cell, a load from the
-    persistent cache: the executable the trace saw)."""
-    import jax
-    import jax.numpy as jnp
-
-    from benchmark.kinds.stream import spec_of
-    from wittgenstein_tpu.serve.registry import CompileRegistry
-    spec = spec_of(cfg, mix)
-    proto = spec.build_protocol()
-    chunk = CompileRegistry().chunk_fn(spec, None, proto=proto).__wrapped__
-    state = jax.eval_shape(jax.vmap(proto.init),
-                           jax.ShapeDtypeStruct((mix["seeds"],), jnp.int32))
-    return {f"jit_{chunk.__name__}":
-            op_names(chunk.lower(*state).compile().as_text())}
-
-
-def run(name: str, seed: int, seconds: float, t0: float, pre_start: float,
-        require_device=True, repo=None) -> dict:
-    """The cell's traced result line with ``breakdown.device_stages`` and
-    ``stages``."""
-    from benchmark import harness
-    from wittgenstein_tpu.serve.registry import compile_counters
-    repo = repo or harness.REPO
-    bench = harness.load_benchmark(repo)
-    c = harness.cell(bench, name)
-    cfg = harness.config(bench, c["config"], repo)
-    mix = harness.traffic(c["traffic"], repo / "benchmark")
-    counters = compile_counters()
-    seed_sim_ms = mix["seeds"] * mix["chunk_ms"] * mix["trace_chunks"]
-    table = {}
-    plain = trace_mod.reduce_dir
-
-    # kinds/stream.py deletes the trace after its own reduction, so the
-    # stage reduction rides on that call (PERF.md section 7: the edit
-    # that lets the stream kind report the stages itself).  It comes
-    # right after the window, before anything compiles again.
-    def reduce_both(tdir):
-        table["setup"] = counters.totals()
-        red = plain(tdir)
-        table["red"] = reduce_dir(tdir, chunk_op_names(cfg, mix),
-                                  seed_sim_ms)
-        return red
-
-    trace_mod.reduce_dir = reduce_both
-    try:
-        res = harness.run_cell(name, seed, seconds, True, t0, pre_start,
-                               require_device=require_device, repo=repo)
-    finally:
-        trace_mod.reduce_dir = plain
-    red = table.get("red")
-    window_s = res["device"].get("window_s")
-    res.setdefault("breakdown", {})["device_stages"] = device_stages(red)
-    res["stages"] = {"ms_per_simms": red and red.get("ms_per_simms"),
-                     "sub_s": red and red["sub"],
-                     "busy_s": red and red["busy_s"],
-                     "unnamed_ops": red and red["unnamed_ops"],
-                     "carry_ops": red and red["carry_ops"],
-                     "setup": table.get("setup"),
-                     "s_per_chunk": window_s and
-                     window_s / mix["trace_chunks"]}
-    return res
-
-
-def main(argv=None, t0=None):
-    from benchmark import harness
-    t0 = time.monotonic() if t0 is None else t0
-    pre_start = harness.since_process_start() - (time.monotonic() - t0)
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float,
-                    default=harness.load_benchmark()["run_seconds"])
-    a = ap.parse_args(argv)
-    harness.setup_jax()
-    try:
-        res = run(a.workload, a.seed, a.seconds, t0, pre_start)
-    except harness.NoChip as e:
-        print(f"stages: {e}", file=sys.stderr)
-        return 3
-    print(json.dumps(res), flush=True)
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main(sys.argv[1:], T0))
+def chunk_op_names(program, state) -> dict:
+    """`op_names` of a chunk program (a registry's `chunk_fn`), compiled
+    for `state` (shapes, e.g. `jax.eval_shape` of the init): in a
+    process that ran it, a load from the persistent cache, the
+    executable the trace saw."""
+    fn = program.__wrapped__
+    return {f"jit_{fn.__name__}":
+            op_names(fn.lower(*state).compile().as_text())}

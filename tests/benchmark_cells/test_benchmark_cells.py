@@ -1,11 +1,13 @@
 """CPU tests of the benchmark (`benchmark/`, `BENCHMARK.json`).
 
-A tiny cell is added as files only (a config, a traffic mix and a
-BENCHMARK.json entry in a scratch checkout) and driven through the
-whole harness with the look for a chip skipped: the result line of both
-traffic kinds, the control and each fault the cells can have (which
-must all come out not correct), the trace reduction on a small recorded
-trace with nested events, and the contract's static rules.
+A tiny cell is added as files only (a config, a traffic mix, a copy of
+the reference modules and a BENCHMARK.json entry in a scratch checkout)
+and driven through the whole harness with the look for a chip skipped:
+the result line of both traffic kinds, the control and each fault the
+cells can have (which must all come out not correct), the reference
+taken from the cell's checkout (and refused there when missing or
+claimed twice), the trace reduction on a small recorded trace with
+nested events, and the contract's static rules.
 """
 
 import ast
@@ -24,7 +26,7 @@ import pytest
 REPO = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(REPO))
 
-from benchmark import harness, trace  # noqa: E402
+from benchmark import check, harness, trace  # noqa: E402
 from benchmark.control import CONTROL  # noqa: E402
 
 BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
@@ -39,7 +41,11 @@ def test_cell_lookup_by_name(cell):
     c = harness.cell(BENCH, cell)
     cfg = harness.config(BENCH, c["config"])
     mix = harness.traffic(c["traffic"])
-    assert cfg["spec"]["protocol"] == "Handel"
+    import wittgenstein_tpu.models  # noqa: F401  (registers protocols)
+    from wittgenstein_tpu.core.protocol import get_protocol
+    protocol = cfg["spec"]["protocol"]
+    assert get_protocol(protocol)
+    assert check.reference(harness.ROOT, protocol).PROTOCOL == protocol
     assert harness.kind(mix["kind"]).run
     names = {m["name"] for m in harness.metrics_of(BENCH, cell, False)}
     assert {"sim_ms_per_s", "setup_s", "peak_hbm_gb"} <= names
@@ -47,19 +53,36 @@ def test_cell_lookup_by_name(cell):
     assert layers and all(m["moves"] in names for m in layers)
 
 
-@pytest.mark.parametrize("metric", [m["name"] for m in BENCH["end_to_end"]
-                                    + BENCH["per_layer"]]
-                         + ["serve.settle_share", "serve.launch_share"])
+#: every metric, and the served kind's readers, which no cell reads yet
+METRICS = [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]] \
+    + ["serve.settle_share", "serve.launch_share"]
+#: a record as a run leaves it, without a trace or the served spans
+RECORD = {"window": {"seconds": 2.0, "seed_sim_ms": 80, "lockstep_ms": 40},
+          "setup": {"setup_s": 1.0, "load_s": 0.4,
+                    "compile": {"trace_s": 3.0, "compile_s": 2.0}},
+          "device": {"memory_peak_bytes": 10 ** 9}}
+
+
+@pytest.mark.parametrize("metric", METRICS)
 def test_every_metric_has_a_reader(metric):
     read = harness.reader(metric)
-    record = {"window": {"seconds": 2.0, "seed_sim_ms": 80,
-                         "lockstep_ms": 40},
-              "setup": {"setup_s": 1.0, "load_s": 0.4},
-              "device": {"memory_peak_bytes": 10 ** 9}}
-    if metric.startswith(("device.", "engine.", "serve.")):
-        assert read(record) is None        # nothing to read: left out
+    if metric.startswith(("device.", "engine.", "serve.", "protocol.")):
+        assert read(RECORD) is None        # nothing to read: left out
     else:
-        assert read(record) > 0
+        assert read(RECORD) > 0
+
+
+@pytest.mark.parametrize("metric", METRICS)
+def test_every_metric_reads_a_traced_record(metric):
+    """With a reduced trace (`kinds/stream.py`) and the served spans in
+    the record, every metric reads a number."""
+    record = json.loads(json.dumps(RECORD))
+    record["trace"] = {"busy_s": 1.5, "ops": 400.0,
+                       "stages": {"engine.inbox": 0.3, "protocol.step": 6.3,
+                                  "engine.route": 1.0, "engine.bin": 2.7,
+                                  "engine.carry": 3.3}}
+    record["serve"] = {"window_s": 2.0, "launch_s": 1.5, "settle_s": 0.1}
+    assert harness.reader(metric)(record) > 0
 
 
 def test_peaks_table_refuses_unknown_devices():
@@ -79,35 +102,35 @@ def test_reference_imports_nothing_of_the_program():
                                for m in mods), path
 
 
-@pytest.mark.parametrize("config", ["handel-2048-exact",
-                                    "handel-cardinal-65536"])
+@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
 def test_reference_follows_the_program_ms_by_ms(config):
-    """The numpy reference against the program's own per-ms engine at 64
-    nodes, every compared value at every ms (both on the CPU)."""
+    """The protocol's numpy reference against the program's own per-ms
+    engine, both cut by the reference's `TINY` and on the CPU: every
+    compared value at every ms."""
     import numpy as np
 
-    from benchmark import check
-    from benchmark.reference import sim
+    from benchmark.kinds.stream import reference_params
     from wittgenstein_tpu.core.network import step_ms
-    from wittgenstein_tpu.models.handel import Handel
-    params = dict(json.loads((REPO / "benchmark/configs" /
-                              f"{config}.json").read_text())
-                  ["spec"]["params"])
-    params.update(node_count=64, nodes_down=6, threshold=57)
+    from wittgenstein_tpu.serve.spec import ScenarioSpec
+    body = dict(harness.config(BENCH, config)["spec"])
+    ref = check.reference(harness.ROOT, body["protocol"])
+    body["params"] = {**body["params"], **ref.TINY}
+    spec = ScenarioSpec.from_json(body)
     seed, ms = 2 ** 31 - 7, 120
-    proto = Handel(**params)
+    proto = spec.build_protocol()
     net, ps = jax.jit(proto.init)(np.int32(seed))
     step = jax.jit(lambda n, p: step_ms(proto, n, p))
-    ref = sim.trajectory(params, seed, range(1, ms + 1),
-                         check.device_tables())
+    want = ref.trajectory(reference_params(spec), seed, range(1, ms + 1),
+                          check.device_tables(ref))
     for t in range(ms):
         net, ps = step(net, ps)
-        got = {f: getattr(net.nodes, f) for f in sim.NODE_FIELDS}
-        got.update({f: getattr(ps, f) for f in sim.PROTO_FIELDS},
-                   time=net.time, dropped=net.dropped,
-                   clamped=net.clamped, evicted=ps.evicted)
+        got = {f: getattr(net.nodes, f) for f in ref.NODE_FIELDS}
+        got.update({f: getattr(net, f) for f in check.ENGINE_LOSSES},
+                   time=net.time)
+        got.update({f: getattr(ps, f)
+                    for f in ref.PROTO_FIELDS + ref.PROTO_LOSSES})
         for f, v in got.items():
-            assert np.array_equal(np.asarray(v), ref[t][f]), (t, f)
+            assert np.array_equal(np.asarray(v), want[t][f]), (t, f)
 
 
 def test_reference_is_no_copy_of_the_program():
@@ -198,6 +221,8 @@ def _tiny_repo(tmp_path, mix_name, mix, per_layer=()):
     (bdir / "configs").mkdir(parents=True)
     (bdir / "traffic").mkdir()
     shutil.copytree(REPO / "benchmark" / "metrics", bdir / "metrics")
+    shutil.copytree(REPO / "benchmark" / "reference", bdir / "reference",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     cfg = json.loads((REPO / "benchmark/configs/handel-2048-exact.json")
                      .read_text())
     cfg["spec"]["params"].update(node_count=64, nodes_down=6,
@@ -222,7 +247,7 @@ STREAM = {"kind": "stream", "seeds": 3, "chunk_ms": 20, "span_ms": 40,
           "trace_chunks": 2, "check": {"seeds": 3, "chunks": 2}}
 SERVED = {"kind": "served", "clients": 2, "seeds_per_request": 2,
           "sim_ms": 40, "chunk_ms": 20, "obs": ["metrics", "audit"],
-          "check": {"requests": 2}}
+          "trace_requests": 1, "check": {"requests": 2}}
 SERVE_LAYERS = [{"name": n, "unit": "%", "better": "lower",
                  "source": "program_span", "layer": "request plane",
                  "moves": "sim_ms_per_s"}
@@ -259,37 +284,53 @@ def test_tiny_stream_cell_added_as_files(tmp_path, trace_on):
     _assert_result_line(_run(repo, trace_on), trace_on)
 
 
-def test_tiny_served_cell_added_as_files(tmp_path):
+def test_tiny_served_cell_added_as_files(tmp_path, monkeypatch):
+    """Both runs compile nothing in the window, whichever groups the
+    clients form: set-up ran each group width once."""
+    from benchmark.kinds import served
+    records, run = [], served.run
+    monkeypatch.setattr(served, "run",
+                        lambda ctx: records.append(run(ctx)) or records[-1])
     repo = _tiny_repo(tmp_path, "tiny-served", SERVED, SERVE_LAYERS)
     _assert_result_line(_run(repo), False)
     res = _run(repo, trace_on=True)
     assert {"serve.settle_share", "serve.launch_share"} \
         <= set(res["metrics"])
+    assert res["correct"] is True
+    assert res["attempted"] == SERVED["clients"] * SERVED["trace_requests"]
+    assert [r["serve"]["compile_s"] for r in records] == [0.0, 0.0]
 
+
+# A fault wraps a chunk program; what follows the state in its output
+# (the served planes' carries) passes through.
 
 def _unchanged(chunk):
-    return lambda nets, ps: (nets, ps)
+    return lambda nets, ps: (nets, ps) + tuple(chunk(nets, ps)[2:])
 
 
 def _half_batch(chunk):
     def run(nets, ps):
-        new = chunk(nets, ps)
+        out = chunk(nets, ps)
         h = nets.time.shape[0] // 2
-        return jax.tree.map(lambda a, b: a.at[h:].set(b[h:]), new,
-                            (nets, ps))
+        return jax.tree.map(lambda a, b: a.at[h:].set(b[h:]),
+                            tuple(out[:2]), (nets, ps)) + tuple(out[2:])
     return run
 
 
 def _altered(chunk):
     def run(nets, ps):
-        nets, ps = chunk(nets, ps)
+        nets, ps, *rest = chunk(nets, ps)
         sent = nets.nodes.msg_sent.at[:, 0].add(1)
-        return nets.replace(nodes=nets.nodes.replace(msg_sent=sent)), ps
+        return (nets.replace(nodes=nets.nodes.replace(msg_sent=sent)), ps,
+                *rest)
     return run
 
 
-@pytest.mark.parametrize("fault", ["control", "unchanged", "half_batch",
-                                   "altered"])
+FAULTS = {"unchanged": _unchanged, "half_batch": _half_batch,
+          "altered": _altered}
+
+
+@pytest.mark.parametrize("fault", ["control", *FAULTS])
 def test_control_and_faults_are_not_correct(tmp_path, fault):
     """Each fault the stream cells can have, planted under the timed
     path, and the control (one receive counter bumped at one ms, the
@@ -297,8 +338,7 @@ def test_control_and_faults_are_not_correct(tmp_path, fault):
     cells run on one chip, so there is no exchange to leave out.)"""
     repo = _tiny_repo(tmp_path, "tiny-stream", STREAM)
     kw = ({"attack": CONTROL} if fault == "control" else
-          {"patch": {"unchanged": _unchanged, "half_batch": _half_batch,
-                     "altered": _altered}[fault]})
+          {"patch": FAULTS[fault]})
     res = _run(repo, seconds=0.5, **kw)
     assert res["correct"] is False and res["failed"] > 0
 
@@ -306,6 +346,65 @@ def test_control_and_faults_are_not_correct(tmp_path, fault):
 def test_served_control_is_not_correct(tmp_path):
     repo = _tiny_repo(tmp_path, "tiny-served", SERVED)
     assert _run(repo, seconds=0.5, attack=CONTROL)["correct"] is False
+
+
+@pytest.mark.parametrize("fault", list(FAULTS))
+def test_served_faults_are_not_correct(tmp_path, fault):
+    """The same faults under every program the request plane launches
+    (the served cell's `check.requests` sample is one request)."""
+    mix = {**SERVED, "check": {"requests": 1}}
+    repo = _tiny_repo(tmp_path, "tiny-served", mix)
+    res = _run(repo, seconds=0.5, patch=FAULTS[fault])
+    assert res["correct"] is False and res["failed"] > 0
+
+
+# --------------------------------------------------------- references
+
+OFF_BY_ONE = """
+
+_trajectory = trajectory
+
+
+def trajectory(*args):
+    out = _trajectory(*args)
+    out[-1]["msg_received"][0] += 1
+    return out
+"""
+
+
+@pytest.mark.parametrize("reference", ["copy", "off_by_one"])
+def test_reference_is_taken_from_the_cells_checkout(tmp_path, reference):
+    """The check runs the reference modules under the cell's checkout:
+    a copy of them passes the cell, and the same copy with one value of
+    its trajectory off by one fails it."""
+    repo = _tiny_repo(tmp_path, "tiny-stream", STREAM)
+    if reference == "off_by_one":
+        sim = repo / "benchmark" / "reference" / "sim.py"
+        sim.write_text(sim.read_text() + OFF_BY_ONE)
+    res = _run(repo, seconds=0.5)
+    assert res["correct"] is (reference == "copy")
+    assert res["checks"]["mismatched_values"]["value"] == (
+        0 if reference == "copy" else STREAM["check"]["seeds"])
+
+
+def test_a_protocol_without_a_reference_is_refused_before_the_window(
+        tmp_path):
+    repo = _tiny_repo(tmp_path, "tiny-stream", STREAM)
+    ref_dir = repo / "benchmark" / "reference"
+    (ref_dir / "sim.py").unlink()
+    built = []
+    with pytest.raises(check.NoReference,
+                       match=re.escape(str(ref_dir.resolve()))):
+        _run(repo, patch=lambda chunk: built.append(chunk) or chunk)
+    assert not built                 # refused before any program
+
+
+def test_two_references_claiming_one_protocol_are_refused(tmp_path):
+    repo = _tiny_repo(tmp_path, "tiny-stream", STREAM)
+    ref_dir = repo / "benchmark" / "reference"
+    shutil.copy(ref_dir / "sim.py", ref_dir / "sim_copy.py")
+    with pytest.raises(check.NoReference, match=r"'sim\.py', 'sim_copy\.py'"):
+        _run(repo)
 
 
 def test_no_chip_no_result():

@@ -4,9 +4,10 @@ A small recorded trace in the TPU's shape (an ops line of HLO texts, a
 modules line, the compiled module's ``op_name`` paths): scopes under
 ``vmap(...)``, a fused op, an unscoped copy (the carry), a second
 program's ops (named, not staged), and the stages' union against the
-busy time `trace.reduce` gives.  A trace whose chunk program carries no
-scope reads None.  The traced tiny stream cell, run through the stage
-entry on the CPU, reports the set-up's trace and compile seconds.
+busy time `trace.reduce` gives, and the stage metrics' readers on it.  A
+trace whose chunk program carries no scope reads None.  The traced tiny
+stream cell, run through the harness on the CPU, reports the set-up's
+trace and compile seconds.
 """
 
 import json
@@ -21,7 +22,7 @@ import pytest
 REPO = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(REPO))
 
-from benchmark import stages, trace  # noqa: E402
+from benchmark import harness, stages, trace  # noqa: E402
 
 MS = 1_000_000
 ROOT = "jit(chunk_batched)/while/body/closed_call"
@@ -178,6 +179,8 @@ def _tiny_repo(tmp_path):
     (bdir / "configs").mkdir(parents=True)
     (bdir / "traffic").mkdir()
     shutil.copytree(REPO / "benchmark" / "metrics", bdir / "metrics")
+    shutil.copytree(REPO / "benchmark" / "reference", bdir / "reference",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     cfg = json.loads((REPO / "benchmark/configs/handel-2048-exact.json")
                      .read_text())
     cfg["spec"]["params"].update(node_count=64, nodes_down=6,
@@ -198,12 +201,32 @@ def _tiny_repo(tmp_path):
     return tmp_path
 
 
+STAGE_METRICS = {f"{s}_ms_per_simms": s for s in stages.STAGES
+                 + (stages.CARRY,)}
+
+
+def test_stage_metrics_read_the_recorded_trace():
+    names = {"jit_chunk_batched": stages.op_names(HLO)}
+    red = stages.reduce([(OPS, MODULES)], HOST, names, seed_sim_ms=40)
+    record = {"trace": {"stages": red["ms_per_simms"]}}
+    got = {m: harness.reader(m)(record) for m in STAGE_METRICS}
+    assert got == pytest.approx({m: 1000.0 * red["stages"][s] / 40
+                                 for m, s in STAGE_METRICS.items()})
+    # the five stages and the other module make up busy time
+    assert sum(got.values()) + 1000.0 * 0.008 / 40 == pytest.approx(
+        1000.0 * red["busy_s"] / 40)
+    assert all(harness.reader(m)({"trace": {"stages": None}}) is None
+               for m in STAGE_METRICS)
+
+
 def test_traced_tiny_cell_reports_setup_trace_and_compile(tmp_path):
-    res = stages.run("tiny.cell", 2 ** 31 + 777, 0.5, time.monotonic(),
-                     0.0, require_device=False, repo=_tiny_repo(tmp_path))
+    res = harness.run_cell("tiny.cell", 2 ** 31 + 777, 0.5, True,
+                           time.monotonic(), 0.0, require_device=False,
+                           repo=_tiny_repo(tmp_path))
     assert res["correct"] is True
-    setup = res["stages"]["setup"]
-    assert setup["trace_s"] > 0 and setup["compile_s"] > 0
+    metrics = res["metrics"]
+    assert metrics["setup.trace_s"]["value"] > 0
+    assert metrics["setup.compile_s"]["value"] > 0
     # the CPU has no accelerator plane: no stage table, nothing made up
-    assert res["stages"]["ms_per_simms"] is None
-    assert res["breakdown"]["device_stages"] == []
+    assert not set(STAGE_METRICS) & set(metrics)
+    assert "breakdown" not in res
